@@ -1,10 +1,13 @@
 """Distributed nested dissection: OPC parity vs the host driver and
 wall-clock across virtual device counts.
 
-Needs multiple host devices; when the current process has fewer than 8 it
-re-execs itself in a subprocess with ``XLA_FLAGS=
---xla_force_host_platform_device_count=8`` (the flag must be set before
-jax initializes).  Emits ``BENCH_dnd.json``:
+With ``JAX_PLATFORMS=cpu`` it needs 8 virtual host devices: unless
+``XLA_FLAGS`` already asks for them it re-execs itself with
+``--xla_force_host_platform_device_count=8`` (the flag must be set before
+jax initializes; the decision is read from the environment, so the child
+never needs a chip the parent holds).  On any other platform it runs in
+this process on the devices present, sweeping the counts of
+``DEVICE_COUNTS`` that fit.  Emits ``BENCH_dnd.json``:
 
   * per-graph OPC of ``distributed_nested_dissection`` on 8 shards vs host
     ``nested_dissection`` at nproc=8 (same seed) — the mean ratio is
@@ -62,13 +65,13 @@ import sys
 import time
 
 DEVICE_COUNTS = (1, 2, 4, 8)
+_DEVICE_FLAG = "--xla_force_host_platform_device_count"
 
 
 def _reexec_with_devices() -> None:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+                        + f" {_DEVICE_FLAG}=8").strip()
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-m", "benchmarks.dnd_bench"],
                          env=env)
@@ -88,8 +91,8 @@ def workload():
 
 
 def main() -> None:
-    import jax
-    if len(jax.devices()) < max(DEVICE_COUNTS):
+    if (os.environ.get("JAX_PLATFORMS") == "cpu"
+            and _DEVICE_FLAG not in os.environ.get("XLA_FLAGS", "")):
         _reexec_with_devices()
         return
     # REPRO_TRACE_OUT=path captures a span trace of the whole bench run
@@ -118,10 +121,13 @@ def _bench() -> None:
     from repro.sparse.symbolic import nnz_opc
     from repro.util import enable_compile_cache
     enable_compile_cache()
+    import jax
+    dev = jax.devices()
+    counts = tuple(p for p in DEVICE_COUNTS if p <= len(dev))
 
     graphs = workload()
     per_graph = {}
-    wall = {p: 0.0 for p in DEVICE_COUNTS}
+    wall = {p: 0.0 for p in counts}
     ratios = []
     max_gather = 0
     stage_s = {}
@@ -135,7 +141,7 @@ def _bench() -> None:
         perm_h = nested_dissection(g, seed=0, nproc=8)
         opc_h = nnz_opc(g, perm_h)[1]
         entry = {"n": g.n, "opc_host": opc_h}
-        for p in DEVICE_COUNTS:
+        for p in counts:
             dg = distribute(g, p)
             # the endpoints of the gated p8/p1 ratio are timed as the
             # min of THREE runs with the first discarded as warmup:
@@ -144,8 +150,7 @@ def _bench() -> None:
             # (the first sample carries compile / cache-load, e.g.
             # grid2d-24 t_p8 10.8 vs 2.4).  The steady-state reps
             # measure the dispatch cost the frontier claim is about
-            reps = 3 if p in (min(DEVICE_COUNTS), max(DEVICE_COUNTS)) \
-                else 1
+            reps = 3 if p in (min(counts), max(counts)) else 1
             samples = []
             fm_rep_s = []
             for rep in range(reps):
@@ -171,12 +176,12 @@ def _bench() -> None:
             # FM-section jitter, tracked separately: the fm stage gate
             # below compares against a wall-clock baseline, so its own
             # run-to-run swing must be visible in the artifact
-            if p == max(DEVICE_COUNTS) and len(fm_rep_s) > 2:
+            if p == max(counts) and len(fm_rep_s) > 2:
                 fm_steady = fm_rep_s[1:]
                 timing_jitter_fm = max(
                     timing_jitter_fm,
                     max(fm_steady) / max(min(fm_steady), 1e-9))
-            if p == max(DEVICE_COUNTS):
+            if p == max(counts):
                 opc_d = nnz_opc(g, perm_d)[1]
                 entry["opc_dnd"] = opc_d
                 entry["opc_ratio"] = round(opc_d / opc_h, 4)
@@ -202,17 +207,17 @@ def _bench() -> None:
                     l["words_dense"] for l in ins.launches
                     if l["kind"] == "dmatch")
         per_graph[name] = entry
-        row(f"dnd/{name}", entry[f"t_p8_s"] * 1e6,
+        row(f"dnd/{name}", entry[f"t_p{max(counts)}_s"] * 1e6,
             n=g.n, opc_ratio=entry["opc_ratio"],
             max_gather=entry["max_gather"],
             budget_ok=entry["launch_budget_ok"],
-            **{f"t_p{p}": entry[f"t_p{p}_s"] for p in DEVICE_COUNTS})
+            **{f"t_p{p}": entry[f"t_p{p}_s"] for p in counts})
 
     # unified-router multi-request drain: N=3 concurrent distributed
     # orderings through ONE shared WaveRouter vs 3 sequential drains —
     # same permutations, strictly fewer collective launches (the wave
     # router's reason to exist)
-    p_hi0 = max(DEVICE_COUNTS)
+    p_hi0 = max(counts)
     r_items = (list(graphs.items()) * 3)[:3]
     r_seeds = [11, 23, 37]
     r_dgs = [distribute(g, p_hi0) for _, g in r_items]
@@ -261,7 +266,7 @@ def _bench() -> None:
     band_name, band_g = next(iter(graphs.items()))
     band_cfg = DNDConfig(centralize_threshold=256,
                          band_central_threshold=128)
-    dg = distribute(band_g, max(DEVICE_COUNTS))
+    dg = distribute(band_g, max(counts))
     t0 = time.perf_counter()
     with track_band_stats() as bstats:
         perm_b = distributed_nested_dissection(dg, seed=0, cfg=band_cfg)
@@ -283,11 +288,13 @@ def _bench() -> None:
         kicks=band["repair_kicks"], pulls=band["ghost_pulls"])
 
     ratio_mean = float(np.mean(ratios))
-    p_lo, p_hi = min(DEVICE_COUNTS), max(DEVICE_COUNTS)
+    p_lo, p_hi = min(counts), max(counts)
     p8_over_p1 = wall[p_hi] / wall[p_lo] if wall[p_lo] else 0.0
     out = {
+        "device": {"platform": dev[0].platform, "kind": dev[0].device_kind,
+                   "count": len(dev)},
         "graphs": per_graph,
-        "wallclock_s": {str(p): round(wall[p], 3) for p in DEVICE_COUNTS},
+        "wallclock_s": {str(p): round(wall[p], 3) for p in counts},
         "p8_over_p1": round(p8_over_p1, 3),
         "timing_jitter": round(timing_jitter, 3),
         "timing_jitter_fm": round(timing_jitter_fm, 3),
@@ -331,7 +338,10 @@ def _bench() -> None:
     # structural per-sibling-launch regression is asserted directly by
     # the launch-budget checks above; this bound (measured 6.2x, jitter
     # <= 1.3x) only catches wholesale launch-growth blowups
-    assert p8_over_p1 <= 7.5, (
+    # the two wall-clock gates below are calibrated on the 8-virtual-
+    # device CPU runner and say nothing about another platform
+    on_cpu = dev[0].platform == "cpu"
+    assert not on_cpu or p8_over_p1 <= 7.5, (
         f"p=8 wall-clock is {p8_over_p1:.2f}x p=1 — frontier batching "
         "regressed toward per-sibling launch growth "
         "(post-fusion baseline 6.2x)")
@@ -359,7 +369,7 @@ def _bench() -> None:
     # (cold rep: compile 31.571 + dispatch 37.763 on the same
     # 8-virtual-device CPU runner class this bench targets)
     fm_total = stage_s.get("fm", 0.0)
-    assert fm_total <= 0.55 * 69.334, (
+    assert not on_cpu or fm_total <= 0.55 * 69.334, (
         f"stage_s.fm {fm_total:.1f}s > 0.55x the 69.334s pre-fusion "
         "baseline — the fused FM pass loop regressed")
 

@@ -5,11 +5,13 @@
 Part 1 sweeps the simulated process count and shows the paper's headline
 result: PT-Scotch ordering quality is stable (or improves) with p while the
 ParMETIS-like baseline degrades.  Part 2 runs the halo-exchange/BFS data
-plane over an 8-way shard_map mesh (host devices).
+plane over an 8-way shard_map mesh: with ``JAX_PLATFORMS=cpu`` on 8
+virtual host devices, elsewhere on the devices the platform has.
 """
 import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import time
 

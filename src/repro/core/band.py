@@ -59,10 +59,16 @@ def bfs_distance_multi(nbr: jax.Array, src: jax.Array, width: int
 
 
 def bfs_mode_default() -> str:
-    """Band-BFS backend: REPRO_BFS_MODE=jnp|pallas|auto (TPU → Mosaic)."""
+    """Band-BFS backend: REPRO_BFS_MODE=jnp|pallas|auto.
+
+    ``auto`` is the fused-XLA path (``jnp``) on every platform: on TPU
+    the v5e compiler refuses the ``bfs_multi`` kernel (its ``(1, n)``
+    blocks break the (8, 128) block rule), and on CPU hosts Pallas would
+    only run in interpret mode.
+    """
     mode = os.environ.get("REPRO_BFS_MODE", "auto")
     if mode == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return "jnp"
     return mode
 
 

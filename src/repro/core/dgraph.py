@@ -70,7 +70,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
@@ -222,11 +221,12 @@ def distribute(g: Graph, nparts: int,
 
 @functools.lru_cache(maxsize=None)
 def make_parts_mesh(nparts: int) -> Mesh:
-    devs = jax.devices()[:nparts]
-    assert len(devs) == nparts, (
-        f"need {nparts} devices, have {len(jax.devices())} "
-        "(set XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    return Mesh(np.array(devs), ("parts",))
+    devs = jax.devices()
+    if len(devs) < nparts:
+        raise RuntimeError(
+            f"a {nparts}-part mesh needs {nparts} devices; platform "
+            f"{devs[0].platform!r} has {len(devs)}")
+    return Mesh(np.array(devs[:nparts]), ("parts",))
 
 
 # ------------------------------------------------------------------ #
@@ -847,10 +847,10 @@ def _halo_stack_jit(nparts: int, n_loc_max: int, n_ghost_max: int,
     def body(x, gids, vtxdist):
         return _halo_gather(x[:, 0], gids[:, 0], vtxdist)[:, None]
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(None, "parts", None), P(None, "parts", None),
-                             P(None, None)),
-                   out_specs=P(None, "parts", None))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(None, "parts", None),
+                                 P(None, "parts", None), P(None, None)),
+                       out_specs=P(None, "parts", None))
     return jax.jit(fn)
 
 
@@ -942,11 +942,11 @@ def _bfs_stack_jit(nparts: int, n_loc_max: int, dmax: int, n_ghost_max: int,
         dist, _ = jax.lax.scan(step, dist, None, length=width)
         return dist[:, None]
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(None, "parts", None, None),
-                             P(None, "parts", None), P(None, "parts", None),
-                             P(None, None)),
-                   out_specs=P(None, "parts", None))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(None, "parts", None, None),
+                                 P(None, "parts", None),
+                                 P(None, "parts", None), P(None, None)),
+                       out_specs=P(None, "parts", None))
     return jax.jit(fn)
 
 
@@ -1125,17 +1125,20 @@ def _matching_stack_jit(nparts: int, n_loc_max: int, dmax: int,
             match = jnp.where(grant >= 0, grant, match)
             return match, None
 
-        match0 = jnp.full((L, n_loc_max), -1, dtype=jnp.int32)
+        # the rounds make the carry per-shard; the initial fill is not,
+        # so mark it varying over ``parts`` for the scan's type check
+        match0 = jax.lax.pcast(jnp.full((L, n_loc_max), -1, jnp.int32),
+                               "parts", to="varying")
         match, _ = jax.lax.scan(round_fn, match0,
                                 jnp.arange(rounds, dtype=jnp.int32))
         return match[:, None]
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(None, "parts", None, None),
-                             P(None, "parts", None, None),
-                             P(None, "parts", None), P(None, None),
-                             P(None, "parts"), P(None)),
-                   out_specs=P(None, "parts", None))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(None, "parts", None, None),
+                                 P(None, "parts", None, None),
+                                 P(None, "parts", None), P(None, None),
+                                 P(None, "parts"), P(None)),
+                       out_specs=P(None, "parts", None))
     return jax.jit(fn)
 
 

@@ -92,12 +92,14 @@ def fm_lane_count(nproc: int, cap: int, fold_dup: bool,
 def gain_mode_default() -> str:
     """FM gain-recompute backend: REPRO_FM_GAIN=jnp|pallas|auto.
 
-    ``auto`` compiles the Mosaic kernel on TPU and keeps the fused-XLA path
-    on CPU hosts (where Pallas would run in interpret mode anyway).
+    ``auto`` is the fused-XLA path (``jnp``) on every platform: on TPU
+    the v5e compiler refuses the ``sep_gain_multi`` kernel (its ``(1,
+    n)`` blocks break the (8, 128) block rule), and on CPU hosts Pallas
+    would only run in interpret mode.
     """
     mode = os.environ.get("REPRO_FM_GAIN", "auto")
     if mode == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return "jnp"
     return mode
 
 

@@ -30,16 +30,19 @@ def fm_mode_default() -> str:
     """FM refinement path: REPRO_FM_MODE=fused|hoisted|auto.
 
     ``fused`` runs the whole pass loop on device as one Pallas kernel
-    (``kernels.fm_fused``); ``hoisted`` is the pre-fusion reference path
+    (``kernels.fm_fused``); ``hoisted`` is the pre-fusion path
     (``core.fm.fm_refine_multi``: Python pass loop traced into one XLA
-    program, batched gain recompute per pass).  ``auto`` resolves to
-    ``fused`` on every backend — measured faster in both compile and
-    steady-state dispatch even under CPU interpret mode, and the two
-    paths are bit-identical (asserted in ``tests/test_fm_fused.py``).
+    program, batched gain recompute per pass).  The two are
+    bit-identical (asserted in ``tests/test_fm_fused.py``).  ``auto``
+    resolves by platform: ``hoisted`` on TPU, because the v5e compiler
+    refuses the fused kernel (its ``(1, n)`` blocks break the (8, 128)
+    block rule, and its body gathers, scatters and takes an int32
+    argmax, none of which Mosaic lowers); ``fused`` elsewhere, where it
+    runs in interpret mode and measured faster than ``hoisted``.
     """
     mode = os.environ.get("REPRO_FM_MODE", "auto")
     if mode == "auto":
-        return "fused"
+        return "hoisted" if jax.default_backend() == "tpu" else "fused"
     return mode
 
 

@@ -508,7 +508,7 @@ class OrderingService:
             t_chk = time.perf_counter()
             try:
                 perm = inflight.assemble(result)
-            except Exception as err:
+            except faults.RECOVERABLE as err:
                 return self._fail_or_readmit(fp, inflight, exec_s, err)
             inj = faults.active()
             if inj is not None:

@@ -80,6 +80,14 @@ class CorruptResult(RuntimeError):
     or an assembled permutation fails its invariant check."""
 
 
+#: the failure model's own exceptions — the only ones a recovery rung
+#: (degrade, isolate, excise, readmit) acts on.  Anything else is a
+#: program error (tracing, lowering, compile, device) and propagates out
+#: of ``pump()`` / ``drain()`` instead of resolving ``ok`` on a slower
+#: path or ``failed``.
+RECOVERABLE = (FaultError, CorruptResult)
+
+
 def is_transient(exc: BaseException) -> bool:
     """Ladder rung 1 classification: only explicitly-transient faults
     are retried; everything else escalates (degrade/isolate/excise)."""

@@ -295,8 +295,10 @@ def _validate_fm_outs(works: Sequence[FMWork], outs) -> None:
 def _fm_ladder(rec: _Recovery, works: Sequence[FMWork], tags,
                level: int):
     """Run one FM group with retry (rung 1) + degrade (rung 2): on a
-    non-transient failure or invalid output, step the mode ladder and
-    re-dispatch; raises only once the oracle rung itself fails."""
+    non-transient fault or invalid output, step the mode ladder and
+    re-dispatch; raises once the oracle rung itself fails.  A program
+    error (anything outside ``faults.RECOVERABLE``) is never degraded
+    around: a kernel the compiler refuses fails the run."""
     lv = max(level, rec.base_level)
     while True:
         mode = _FM_MODES[lv]
@@ -305,7 +307,7 @@ def _fm_ladder(rec: _Recovery, works: Sequence[FMWork], tags,
                 "fm", tags, lambda: execute_fm_works(works, mode=mode))
             _validate_fm_outs(works, outs)
             return outs
-        except Exception as err:
+        except _faults.RECOVERABLE as err:
             if lv + 1 >= len(_FM_MODES):
                 raise
             lv += 1
@@ -359,20 +361,20 @@ def execute_wave(works: List, level: Optional[int] = None,
 
     def guarded(kind: str, idxs: List[int], run_all, run_one) -> List:
         """Rungs 1+3 around one bucket-group dispatch: retry the whole
-        group, then isolate per-work on terminal failure."""
+        group, then isolate per-work on a terminal fault."""
         if rec is None:
             return run_all()
         tags_l = [tag_of(i) for i in idxs]
         try:
             return rec.retry_loop(kind, tags_l, run_all)
-        except Exception as err:
+        except _faults.RECOVERABLE as err:
             rec.note_isolate(kind, tags_l, err)
             outs: List = []
             for i in idxs:
                 try:
                     outs.append(rec.retry_loop(
                         kind, [tag_of(i)], lambda i=i: run_one(i)))
-                except Exception as e1:
+                except _faults.RECOVERABLE as e1:
                     outs.append(_WorkFailed(e1))
             return outs
 
@@ -392,7 +394,7 @@ def execute_wave(works: List, level: Optional[int] = None,
             g_tags = [tag_of(items[p][0]) for p in poss]
             try:
                 g_outs = _fm_ladder(rec, g_works, g_tags, level)
-            except Exception as err:
+            except _faults.RECOVERABLE as err:
                 rec.note_isolate("fm", g_tags, err)
                 g_outs = []
                 for p in poss:
@@ -401,7 +403,7 @@ def execute_wave(works: List, level: Optional[int] = None,
                         g_outs.append(_fm_ladder(
                             rec, [w], [tag_of(i)],
                             rec.level_of(tag_of(i)))[0])
-                    except Exception as e1:
+                    except _faults.RECOVERABLE as e1:
                         g_outs.append(_WorkFailed(e1))
             for p, r in zip(poss, g_outs):
                 outs[p] = r
@@ -713,9 +715,9 @@ class WaveRouter:
                     try:
                         _advance(t, r, self._blocked)
                         continue
-                    except Exception as adv_err:
-                        # a generator choking on its (possibly faulted)
-                        # result fails only its own tree
+                    except _faults.RECOVERABLE as adv_err:
+                        # a generator that raises a fault fails only its
+                        # own tree; a program error propagates
                         err = adv_err
                 dead.add(id(root))
                 self._excise(root, err)

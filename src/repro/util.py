@@ -6,18 +6,29 @@ import time
 
 _CACHE_ON = False
 
+#: the persistent compile cache's home when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: one fixed directory in the checkout (git-ignored), so every
+#: process of a run, and every later run, hits the same entries
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 
 def enable_compile_cache() -> None:
     """Persistent XLA compilation cache (huge win for the host-recursion
-    control plane, which reuses a small family of jitted kernels)."""
+    control plane, which reuses a small family of jitted kernels).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own reading of it
+    stands and no directory is set here; otherwise the cache lives in
+    ``CACHE_DIR``.
+    """
     global _CACHE_ON
-    if _CACHE_ON or os.environ.get("REPRO_NO_CACHE"):
+    if _CACHE_ON:
         return
     import jax
-    cache_dir = os.environ.get("REPRO_CACHE_DIR",
-                               os.path.expanduser("~/.cache/repro_jax"))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
     _CACHE_ON = True
 
