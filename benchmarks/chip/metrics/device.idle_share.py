@@ -1,0 +1,9 @@
+"""Share of the traced tail (whole orderings from a request boundary)
+in which no operation ran on the device, from the profiler trace
+(``devtrace.reduce_trace``)."""
+
+
+def read(run):
+    if run.trace is None or run.trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])
