@@ -112,9 +112,11 @@ def fm_refine_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
 
     Shapes (L = lanes): nbr (L, n, d) int32; vwgt (L, n); parts_init
     (L, n) int8; locked (L, n) bool; keys (L, 2) uint32; eps_frac (L,)
-    f32; max_moves, n_pert (L,) int32.  Returns (parts, sep_w, imb) with
-    leading lane axis.  The pass loop is hoisted out of the per-lane body
-    so the O(L·n·d) gain recompute runs as ONE batched kernel per pass.
+    f32; max_moves, n_pert (L,) int32.  Returns (parts, sep_w, imb,
+    moves) with leading lane axis; ``moves`` (L, passes, 2) int32 holds
+    each pass's move counters (``fm_move_loop``).  The pass loop is
+    hoisted out of the per-lane body so the O(L·n·d) gain recompute runs
+    as ONE batched kernel per pass.
 
     This is the *hoisted* reference path (``REPRO_FM_MODE=hoisted``);
     the default production path is the fused on-device pass loop
@@ -140,19 +142,22 @@ def fm_refine_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
     bpart, bws, bimb = part, ws, jnp.abs(w0 - w1)
     pert = n_pert                       # perturbation active in pass 1 only
     pass_fn = functools.partial(_fm_pass, pos_only=pos_only)
+    moves = []
     for p in range(passes):
         both = jax.vmap(jax.random.split)(keys)             # (L, 2, 2)
         keys, subs = both[:, 0], both[:, 1]
         # per-pass tiebreak noise (moved-locks make per-move noise redundant)
         noise = jax.vmap(lambda k: jax.random.uniform(k, (2, n)))(subs)
         pulled0, pulled1 = _pulled_all(nbrs, valid, vwgt_f, part, gain_mode)
-        (part, w0, w1, ws, bpart, bws, bimb) = jax.vmap(pass_fn)(
-            nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0, pulled1,
-            w0, w1, ws, bpart, bws, bimb, noise, pert, max_moves)
+        (part, w0, w1, ws, bpart, bws, bimb, iters, last) = jax.vmap(
+            pass_fn)(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
+                     pulled1, w0, w1, ws, bpart, bws, bimb, noise, pert,
+                     max_moves)
+        moves.append(jnp.stack([iters, last], axis=1))      # (L, 2)
         part = bpart                                        # revert to best
         w0, w1, ws = sums(part)
         pert = jnp.zeros_like(pert)
-    return bpart, bws, bimb
+    return bpart, bws, bimb, jnp.stack(moves, axis=1)
 
 
 # --------------------------------------------------------------------- #
@@ -267,6 +272,23 @@ def _select_best(w: FMWork, parts: np.ndarray, sep_w: np.ndarray,
     return parts[best], float(sep_w[best]), float(imb[best])
 
 
+def _launch_counts(moves: np.ndarray, lanes: int) -> dict:
+    """The ``launch`` payload's move-loop counters of one FM dispatch.
+
+    ``trips`` sums, over passes, the most moves any lane ran: the serial
+    iterations of the vmapped move loop, which runs until its slowest
+    lane stops (dummy lanes run none).  The rest are over the ``lanes``
+    real lanes: moves run (``lane_iters``) and moves after each pass's
+    last improvement, which the revert to best throws away
+    (``iters_after_best``).
+    """
+    moves = np.asarray(moves, np.int64)             # (L_pad, passes, 2)
+    iters, last = moves[:lanes, :, 0], moves[:lanes, :, 1]
+    return {"trips": int(moves[:, :, 0].max(axis=0).sum()),
+            "lane_iters": int(iters.sum()),
+            "iters_after_best": int((iters - last).sum())}
+
+
 def execute_fm_works(works: Sequence[FMWork],
                      gain_mode: Optional[str] = None,
                      mode: Optional[str] = None
@@ -325,26 +347,29 @@ def execute_fm_works(works: Sequence[FMWork],
         from repro.core.dgraph import _note_launch
 
         def dispatch():
-            parts, sep_w, imb = fm_refine_batch(
+            return jax.device_get(fm_refine_batch(
                 jnp.asarray(nbr_b), jnp.asarray(vw_b), jnp.asarray(parts_b),
                 jnp.asarray(lock_b), jnp.asarray(keys_b), jnp.asarray(eps_b),
                 jnp.asarray(mm_b), jnp.asarray(np_b), passes=passes,
-                pos_only=pos_only, mode=mode, gain_mode=gain_mode)
-            return np.asarray(parts), np.asarray(sep_w), np.asarray(imb)
+                pos_only=pos_only, mode=mode, gain_mode=gain_mode))
 
         # the compiled program does not depend on the lanes' move
         # budgets (max_moves is traced lane data in both modes), so the
         # jit key — which decides the compile/dispatch billing split —
         # carries only program-shaping fields.  One dispatch:fm span
         # covers all ``passes`` on-device passes of the bucket.
-        parts, sep_w, imb = obs.timed_dispatch(
+        parts, sep_w, imb, moves = obs.timed_dispatch(
             "fm", "fm",
             ("fm", mode, n_pad, d_pad, passes, pos_only, gain_mode, L_pad),
             dispatch, lanes=L_real, lanes_pad=L_pad, mode=mode,
             max_moves=int(mm_b.max()),
             bucket=(n_pad, d_pad, passes, pos_only))
+        # real neighbour slots of the real lanes, from the unpadded tables
+        slots = sum(int(np.count_nonzero(works[i].nbr >= 0)) * k
+                    for i, k in zip(idxs, counts))
         _note_launch("fm", 0, L_real, L_pad,
-                     (n_pad, d_pad, passes, pos_only), passes, 0)
+                     (n_pad, d_pad, passes, pos_only), passes, 0,
+                     slots=slots, **_launch_counts(moves, L_real))
         off = 0
         for i, k in zip(idxs, counts):
             n = works[i].nbr.shape[0]

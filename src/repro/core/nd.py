@@ -25,6 +25,7 @@ from typing import Generator, Optional, Tuple, Union
 
 import numpy as np
 
+from repro.core import dgraph as _dg
 from repro.core.band import BFSWork, execute_bfs_works, extract_band, \
     project_band
 from repro.core.coarsen import MatchWork, coarsen_multilevel_task, \
@@ -138,30 +139,34 @@ def separator_task(g: Graph, seed: int, nproc: int, cfg: NDConfig,
     pos_only = cfg.refine_strict
     n_pert = 0 if pos_only else 8
 
-    # uncoarsen: project, band-extract, multi-sequential FM
+    # uncoarsen: project, band-extract, multi-sequential FM.  The host
+    # steps between yields run under ``stage:band`` spans (never across
+    # a yield, so a span holds this task's own work only).
     for lvl in range(len(state.levels) - 1, 0, -1):
         cmap = state.levels[lvl].cmap
         fine = state.levels[lvl - 1].graph
-        part = _project(part, cmap)
+        with _dg.stage("band"):
+            part = _project(part, cmap)
+            nbr_f, _ = fine.to_ell()
         lvl_seed = mix_seeds(seed, lvl)
         if cfg.use_band:
-            nbr_f, _ = fine.to_ell()
             dist = yield BFSWork(nbr=nbr_f, src=part == 2,
                                  width=cfg.band_width)
-            band, bpart, locked, old_ids = extract_band(
-                fine, part, width=cfg.band_width, dist=dist)
-            nbr_b, _ = band.to_ell()
+            with _dg.stage("band"):
+                band, bpart, locked, old_ids = extract_band(
+                    fine, part, width=cfg.band_width, dist=dist)
+                nbr_b, _ = band.to_ell()
             bpart, _, _ = yield FMWork(
                 nbr=nbr_b, vwgt=band.vwgt, part=bpart, locked=locked,
                 seed=lvl_seed, k_inst=k_fm, eps_frac=cfg.eps_frac,
                 passes=cfg.fm_passes, n_pert=n_pert, pos_only=pos_only)
-            assert separator_is_valid(nbr_b, bpart)
-            part = project_band(part, bpart, old_ids)
+            with _dg.stage("band"):
+                assert separator_is_valid(nbr_b, bpart)
+                part = project_band(part, bpart, old_ids)
         else:
             locked = np.zeros(fine.n, bool)
             if cfg.freeze_interface and nproc > 1:
                 locked |= _interface_frozen(fine, nproc)
-            nbr_f, _ = fine.to_ell()
             part, _, _ = yield FMWork(
                 nbr=nbr_f, vwgt=fine.vwgt, part=part, locked=locked,
                 seed=lvl_seed, k_inst=k_fm, eps_frac=cfg.eps_frac,

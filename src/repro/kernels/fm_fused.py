@@ -17,13 +17,20 @@ all passes:
            │ state (part, pulled0/1, w0, w1, best) resident │
            └────────────────────────────────────────────────┘
              ▼
-    HBM:   bpart[l]  sep_w[l]  imb[l]
+    HBM:   bpart[l]  sep_w[l]  imb[l]  moves[l]
 
 Move budgets are **adaptive per lane**: ``max_moves`` rides in as lane
 data (an ``(L, 1)`` input), so each lane's move loop terminates at its
 own budget — lanes with small budgets are not serialized behind large
 ones, and ``FMWork.bucket_key`` no longer needs the pow2 ``max_moves``
 sub-bucket (fewer buckets ⇒ fewer compiles, wider lane stacks).
+
+Move counters: each pass's move loop also reports how many moves it ran
+(``iters``) and one past the last move that improved the best state
+(``last_better``, 0 if none did); the moves after it are thrown away by
+the revert to best.  They are computed on every call — one more scalar in
+the loop carry — and returned beside the partitions as ``moves``
+``(L, passes, 2)`` int32.
 
 Bit-parity contract: per-pass tiebreak noise is precomputed outside the
 kernel (``fm_noise``) with the exact op sequence of the hoisted path —
@@ -53,7 +60,9 @@ def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
     The per-lane data-plane primitive shared by the hoisted path (under
     ``jax.vmap`` in ``core.fm.fm_refine_multi``) and the fused kernel
     (called per grid lane inside ``_fm_fused_kernel``) — one definition,
-    so the two paths cannot drift.
+    so the two paths cannot drift.  Returns the pass's state, then its
+    move counters ``iters`` (moves run) and ``last_better`` (one past the
+    last move that improved the best state, 0 if none did).
     """
     n, d = nbrs.shape
 
@@ -66,7 +75,7 @@ def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
         selection is O(n) vector ops, the update is O(d²) scatters —
         (beyond-paper optimization vs the naive O(n·d) gain recompute)."""
         (i, alive, part, moved, pulled0, pulled1,
-         w0, w1, ws, bpart, bws, bimb) = carry
+         w0, w1, ws, bpart, bws, bimb, last_better) = carry
         gain0 = vwgt_f - pulled0
         gain1 = vwgt_f - pulled1
         # --- feasibility (balance after move)
@@ -125,15 +134,17 @@ def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
         bpart = jnp.where(better, part, bpart)
         bws = jnp.where(better, ws, bws)
         bimb = jnp.where(better, jnp.minimum(imb_new, bimb), bimb)
+        last_better = jnp.where(better, i + 1, last_better)
         return (i + 1, ok, part, moved, pulled0, pulled1,
-                w0, w1, ws, bpart, bws, bimb)
+                w0, w1, ws, bpart, bws, bimb, last_better)
 
     moved = jnp.zeros(n, bool)
     carry = (jnp.int32(0), jnp.bool_(True), part, moved, pulled0,
-             pulled1, w0, w1, ws, bpart, bws, bimb)
+             pulled1, w0, w1, ws, bpart, bws, bimb, jnp.int32(0))
     carry = jax.lax.while_loop(move_cond, move_body, carry)
-    (_, _, part, _, _, _, w0, w1, ws, bpart, bws, bimb) = carry
-    return part, w0, w1, ws, bpart, bws, bimb
+    (iters, _, part, _, _, _, w0, w1, ws, bpart, bws, bimb,
+     last_better) = carry
+    return part, w0, w1, ws, bpart, bws, bimb, iters, last_better
 
 
 def fm_noise(keys, n: int, passes: int) -> jax.Array:
@@ -154,7 +165,7 @@ def fm_noise(keys, n: int, passes: int) -> jax.Array:
 
 def _fm_fused_kernel(nbr_ref, vwgt_ref, part_ref, locked_ref, noise_ref,
                      eps_ref, mm_ref, np_ref, part_out, bws_out, bimb_out,
-                     *, passes, pos_only):
+                     moves_out, *, passes, pos_only):
     nbr = nbr_ref[0]                          # (n, d) int32, lane-resident
     n, d = nbr.shape
     valid = nbr >= 0
@@ -177,7 +188,7 @@ def _fm_fused_kernel(nbr_ref, vwgt_ref, part_ref, locked_ref, noise_ref,
     bpart, bws, bimb = part, ws, jnp.abs(w0 - w1)
 
     def pass_body(p, carry):
-        part, w0, w1, ws, bpart, bws, bimb = carry
+        part, w0, w1, ws, bpart, bws, bimb, moves = carry
         noise = jax.lax.dynamic_index_in_dim(noise_all, p, 0,
                                              keepdims=False)   # (2, n)
         pert = jnp.where(p == 0, n_pert, 0)    # perturb pass 1 only
@@ -188,20 +199,23 @@ def _fm_fused_kernel(nbr_ref, vwgt_ref, part_ref, locked_ref, noise_ref,
         wn = jnp.where(valid, wn, 0.0)
         pulled0 = jnp.sum(wn * (pn == 1), axis=1)
         pulled1 = jnp.sum(wn * (pn == 0), axis=1)
-        (part, w0, w1, ws, bpart, bws, bimb) = fm_move_loop(
+        (part, w0, w1, ws, bpart, bws, bimb, iters, last) = fm_move_loop(
             nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0, pulled1,
             w0, w1, ws, bpart, bws, bimb, noise, pert, max_moves,
             pos_only=pos_only)
+        moves = moves.at[p].set(jnp.stack([iters, last]))
         part = bpart                           # revert to best
         w0, w1, ws = sums(part)
-        return (part, w0, w1, ws, bpart, bws, bimb)
+        return (part, w0, w1, ws, bpart, bws, bimb, moves)
 
-    carry = (part, w0, w1, ws, bpart, bws, bimb)
+    moves = jnp.zeros((passes, 2), jnp.int32)
+    carry = (part, w0, w1, ws, bpart, bws, bimb, moves)
     carry = jax.lax.fori_loop(0, passes, pass_body, carry)
-    (part, w0, w1, ws, bpart, bws, bimb) = carry
+    (part, w0, w1, ws, bpart, bws, bimb, moves) = carry
     part_out[0] = bpart
     bws_out[0, 0] = bws
     bimb_out[0, 0] = bimb
+    moves_out[0] = moves
 
 
 @functools.partial(jax.jit, static_argnames=("passes", "pos_only",
@@ -214,16 +228,16 @@ def fm_fused_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
     Same contract and shapes as ``core.fm.fm_refine_multi`` (L = lanes):
     nbr (L, n, d) int32; vwgt (L, n); parts_init (L, n) int8; locked
     (L, n) bool; keys (L, 2) uint32; eps_frac (L,) f32; max_moves,
-    n_pert (L,) int32.  Returns (parts int8, sep_w, imb), bit-identical
-    to the hoisted path.  The compiled program does not depend on
-    ``max_moves`` (traced lane data), so works with different budgets
-    share one executable.
+    n_pert (L,) int32.  Returns (parts int8, sep_w, imb, moves),
+    bit-identical to the hoisted path.  The compiled program does not
+    depend on ``max_moves`` (traced lane data), so works with different
+    budgets share one executable.
     """
     L, n, d = nbr.shape
     vwgt_f = vwgt.astype(jnp.float32)
     eps_abs = eps_frac.astype(jnp.float32) * vwgt_f.sum(axis=1)
     noise = fm_noise(keys, n, passes)                       # (L, passes, 2, n)
-    parts, bws, bimb = pl.pallas_call(
+    parts, bws, bimb, moves = pl.pallas_call(
         functools.partial(_fm_fused_kernel, passes=passes,
                           pos_only=pos_only),
         grid=(L,),
@@ -241,14 +255,16 @@ def fm_fused_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
             pl.BlockSpec((1, n), lambda l: (l, 0)),
             pl.BlockSpec((1, 1), lambda l: (l, 0)),
             pl.BlockSpec((1, 1), lambda l: (l, 0)),
+            pl.BlockSpec((1, passes, 2), lambda l: (l, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((L, n), jnp.int32),
             jax.ShapeDtypeStruct((L, 1), jnp.float32),
             jax.ShapeDtypeStruct((L, 1), jnp.float32),
+            jax.ShapeDtypeStruct((L, passes, 2), jnp.int32),
         ],
         interpret=interpret,
     )(nbr, vwgt_f, parts_init.astype(jnp.int32),
       locked.astype(jnp.int32), noise,
       eps_abs[:, None], max_moves[:, None], n_pert[:, None])
-    return parts.astype(jnp.int8), bws[:, 0], bimb[:, 0]
+    return parts.astype(jnp.int8), bws[:, 0], bimb[:, 0], moves

@@ -60,7 +60,9 @@ def fm_refine_batch(nbr, vwgt, parts_init, locked, keys, eps_frac,
     recovery ladder's last kernel rung (DESIGN.md §8), sharing no code
     with the other two.  ``gain_mode`` only applies to the hoisted
     path's per-pass gain recompute backend.  All modes return
-    bit-identical results (asserted in ``tests/test_fm_fused.py``).
+    bit-identical results, ``(parts, sep_w, imb, moves)``, the move
+    counters included (asserted in ``tests/test_fm_fused.py`` and
+    ``tests/test_fm_counters.py``).
     """
     if mode is None:
         mode = fm_mode_default()

@@ -54,7 +54,9 @@ def fm_fused_ref(nbr: jax.Array, vwgt: jax.Array, parts_init: jax.Array,
     (L, passes, 2, n) from ``fm_fused.fm_noise`` and absolute balance
     slack ``eps_abs`` (L,).  All float sums are over integer-valued
     float32 weights, so any reduction order is exact and bit-parity with
-    the kernel is well-defined.  Returns (parts int8, sep_w, imb).
+    the kernel is well-defined.  Returns (parts int8, sep_w, imb, moves):
+    ``moves`` (L, passes, 2) holds each pass's moves run and one past its
+    last improving move (0 if none).
     """
     L, n, d = nbr.shape
 
@@ -70,7 +72,7 @@ def fm_fused_ref(nbr: jax.Array, vwgt: jax.Array, parts_init: jax.Array,
 
         def move_body(carry):
             (i, alive, part, moved, pulled0, pulled1,
-             w0, w1, ws, bpart, bws, bimb, noise, pert) = carry
+             w0, w1, ws, bpart, bws, bimb, noise, pert, last) = carry
             imb = jnp.abs(w0 - w1)
             feas0 = jnp.abs((w0 + vwgt_f) - (w1 - pulled0)) \
                 <= jnp.maximum(eps_abs, imb)
@@ -121,11 +123,12 @@ def fm_fused_ref(nbr: jax.Array, vwgt: jax.Array, parts_init: jax.Array,
             bpart = jnp.where(better, part, bpart)
             bws = jnp.where(better, ws, bws)
             bimb = jnp.where(better, jnp.minimum(imb_new, bimb), bimb)
+            last = jnp.where(better, i + 1, last)
             return (i + 1, ok, part, moved, pulled0, pulled1,
-                    w0, w1, ws, bpart, bws, bimb, noise, pert)
+                    w0, w1, ws, bpart, bws, bimb, noise, pert, last)
 
         def pass_body(p, carry):
-            part, bpart, bws, bimb = carry
+            part, bpart, bws, bimb, moves = carry
             w0, w1, ws = sums(part)
             flat = nbrs.reshape(-1)
             pn = jnp.take(part, flat, axis=0).reshape(nbr.shape)
@@ -136,23 +139,25 @@ def fm_fused_ref(nbr: jax.Array, vwgt: jax.Array, parts_init: jax.Array,
             carry0 = (jnp.int32(0), jnp.bool_(True), part,
                       jnp.zeros(n, bool), pulled0, pulled1, w0, w1, ws,
                       bpart, bws, bimb, noise_all[p],
-                      jnp.where(p == 0, n_pert, 0))
+                      jnp.where(p == 0, n_pert, 0), jnp.int32(0))
             out = jax.lax.while_loop(
                 lambda c: (c[0] < max_moves) & c[1], move_body, carry0)
-            return (out[9], out[9], out[10], out[11])   # part <- best
+            moves = moves.at[p, 0].set(out[0]).at[p, 1].set(out[14])
+            return (out[9], out[9], out[10], out[11], moves)  # part <- best
 
         w0, w1, ws = sums(part)
-        carry = (part, part, ws, jnp.abs(w0 - w1))
-        part, bpart, bws, bimb = jax.lax.fori_loop(0, passes, pass_body,
-                                                   carry)
-        return bpart, bws, bimb
+        carry = (part, part, ws, jnp.abs(w0 - w1),
+                 jnp.zeros((passes, 2), jnp.int32))
+        part, bpart, bws, bimb, moves = jax.lax.fori_loop(
+            0, passes, pass_body, carry)
+        return bpart, bws, bimb, moves
 
-    parts, bws, bimb = jax.vmap(one_lane)(
+    parts, bws, bimb, moves = jax.vmap(one_lane)(
         jnp.asarray(nbr, jnp.int32), vwgt.astype(jnp.float32),
         parts_init.astype(jnp.int32), jnp.asarray(locked, bool),
         noise, eps_abs.astype(jnp.float32),
         jnp.asarray(max_moves, jnp.int32), jnp.asarray(n_pert, jnp.int32))
-    return parts.astype(jnp.int8), bws, bimb
+    return parts.astype(jnp.int8), bws, bimb, moves
 
 
 def diffusion_step_ref(nbr: jax.Array, val: jax.Array, x: jax.Array,

@@ -726,8 +726,10 @@ class OrderingService:
                     result="missed" if missed else "met")
         tracer = obs.current()
         if tracer is not None:
-            # retrospective request span tree: the latency breakdown is
-            # only known at resolve time (queue_wait then exec)
+            # retrospective request span tree: the queue wait is only
+            # known at resolve time.  The execution has no span of its
+            # own: ``exec_s`` is an apportioned share of waves, not an
+            # interval that happened.
             root = tracer.add_span(
                 "request", t_submit, t_now,
                 attrs={"rid": rid, "fingerprint": fp[:16],
@@ -736,6 +738,4 @@ class OrderingService:
                 tracer.add_span("queue_wait", t_submit,
                                 t_submit + queue_wait,
                                 parent_id=root.span_id)
-            tracer.add_span("exec", t_now - float(exec_s), t_now,
-                            parent_id=root.span_id)
         return res

@@ -302,13 +302,14 @@ class FaultInjector:
 
 def _corrupt_dispatch(kind: str, out):
     """NaN-corrupt a dispatch output (``fm`` only, see ``_SITE_KINDS``):
-    out-of-range parts + NaN weights, certain to fail validation."""
+    out-of-range parts + NaN weights, certain to fail validation.  The
+    move counters that follow them pass through."""
     assert kind == "fm", kind
-    parts, sep_w, imb = out
+    parts, sep_w, imb, moves = out
     parts = np.full_like(np.asarray(parts), 7)
     sep_w = np.full_like(np.asarray(sep_w, dtype=np.float64), np.nan)
     imb = np.full_like(np.asarray(imb, dtype=np.float64), np.nan)
-    return parts, sep_w, imb
+    return parts, sep_w, imb, moves
 
 
 # ------------------------------------------------------------------ #

@@ -30,6 +30,7 @@ from typing import List, Sequence, Union
 import numpy as np
 
 from repro import obs
+from repro.core import dgraph as _dg
 from repro.core.dnd import _Spawn
 from repro.core.graph import Graph
 from repro.core.nd import (NDConfig, child_nprocs, child_seeds,
@@ -69,41 +70,54 @@ def _nd_node_task(g: Graph, gids: np.ndarray, seed: int, nproc: int,
     induced subgraphs of equal structure under equal parts are equal
     structures — so paths align between record and replay by
     construction.
+
+    The host steps run under ``stage:*`` spans, each between two yields:
+    ``leaf_order`` (minimum degree on a leaf), ``split`` (the component
+    split, ``resolve_separator`` and ``split_by_separator``) and
+    ``sep_order`` (the separator's own order).
     """
     if g.n <= cfg.leaf_size:
-        ordering.add_leaf(node, start, gids[leaf_perm(g, seed)])
+        with _dg.stage("leaf_order"):
+            ordering.add_leaf(node, start, gids[leaf_perm(g, seed)])
         return
-    comp = g.components()
-    ncomp = int(comp.max()) + 1
-    if ncomp > 1:                       # independent parts: no separator
+    with _dg.stage("split"):
+        comp = g.components()
+        ncomp = int(comp.max()) + 1
         subs = []
-        off = start
-        for c in range(ncomp):
-            sub, old = g.induced_subgraph(comp == c)
-            child = ordering.add_internal(node, off, sub.n)
-            subs.append(_nd_node_task(sub, gids[old],
-                                      component_seed(seed, c), nproc,
-                                      cfg, ordering, child, off,
-                                      hints, rec, f"{path}.c{c}"))
-            off += sub.n
+        if ncomp > 1:                   # independent parts: no separator
+            off = start
+            for c in range(ncomp):
+                sub, old = g.induced_subgraph(comp == c)
+                child = ordering.add_internal(node, off, sub.n)
+                subs.append(_nd_node_task(sub, gids[old],
+                                          component_seed(seed, c), nproc,
+                                          cfg, ordering, child, off,
+                                          hints, rec, f"{path}.c{c}"))
+                off += sub.n
+    if subs:
         yield _Spawn(subs)
         return
     part = yield from separator_task(
         g, seed, effective_nproc(g.n, nproc, cfg), cfg,
         warm_part=None if hints is None else hints.get(path))
-    part = resolve_separator(g, seed, part, cfg)
+    with _dg.stage("split"):
+        part = resolve_separator(g, seed, part, cfg)
+        if part is not None:
+            (g0, old0), (g1, old1), (gs, olds) = split_by_separator(g, part)
     if part is None:                    # could not split
-        ordering.add_leaf(node, start, gids[leaf_perm(g, seed)])
+        with _dg.stage("leaf_order"):
+            ordering.add_leaf(node, start, gids[leaf_perm(g, seed)])
         return
     if rec is not None:
         rec[path] = part
-    (g0, old0), (g1, old1), (gs, olds) = split_by_separator(g, part)
     p0, p1 = child_nprocs(nproc)
     s0, s1 = child_seeds(seed)
     c0 = ordering.add_internal(node, start, g0.n)
     c1 = ordering.add_internal(node, start + g0.n, g1.n)
-    sperm = separator_perm(gs, seed)
-    ordering.add_leaf(node, start + g0.n + g1.n, gids[olds[sperm]], "sep")
+    with _dg.stage("sep_order"):
+        sperm = separator_perm(gs, seed)
+        ordering.add_leaf(node, start + g0.n + g1.n, gids[olds[sperm]],
+                          "sep")
     yield _Spawn([
         _nd_node_task(g0, gids[old0], s0, p0, cfg, ordering, c0, start,
                       hints, rec, path + ".0"),
