@@ -113,7 +113,7 @@ def fm_refine_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
     Shapes (L = lanes): nbr (L, n, d) int32; vwgt (L, n); parts_init
     (L, n) int8; locked (L, n) bool; keys (L, 2) uint32; eps_frac (L,)
     f32; max_moves, n_pert (L,) int32.  Returns (parts, sep_w, imb,
-    moves) with leading lane axis; ``moves`` (L, passes, 2) int32 holds
+    moves) with leading lane axis; ``moves`` (L, passes, 3) int32 holds
     each pass's move counters (``fm_move_loop``).  The pass loop is
     hoisted out of the per-lane body so the O(L·n·d) gain recompute runs
     as ONE batched kernel per pass.
@@ -149,11 +149,11 @@ def fm_refine_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
         # per-pass tiebreak noise (moved-locks make per-move noise redundant)
         noise = jax.vmap(lambda k: jax.random.uniform(k, (2, n)))(subs)
         pulled0, pulled1 = _pulled_all(nbrs, valid, vwgt_f, part, gain_mode)
-        (part, w0, w1, ws, bpart, bws, bimb, iters, last) = jax.vmap(
+        (part, w0, w1, ws, bpart, bws, bimb, *counts) = jax.vmap(
             pass_fn)(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
                      pulled1, w0, w1, ws, bpart, bws, bimb, noise, pert,
                      max_moves)
-        moves.append(jnp.stack([iters, last], axis=1))      # (L, 2)
+        moves.append(jnp.stack(counts, axis=1))             # (L, 3)
         part = bpart                                        # revert to best
         w0, w1, ws = sums(part)
         pert = jnp.zeros_like(pert)
@@ -278,15 +278,19 @@ def _launch_counts(moves: np.ndarray, lanes: int) -> dict:
     ``trips`` sums, over passes, the most moves any lane ran: the serial
     iterations of the vmapped move loop, which runs until its slowest
     lane stops (dummy lanes run none).  The rest are over the ``lanes``
-    real lanes: moves run (``lane_iters``) and moves after each pass's
+    real lanes: moves run (``lane_iters``), moves after each pass's
     last improvement, which the revert to best throws away
-    (``iters_after_best``).
+    (``iters_after_best``), and moves whose pulled set took more than
+    one scatter round (``pull_overflow``; the oracle does not count it).
     """
-    moves = np.asarray(moves, np.int64)             # (L_pad, passes, 2)
+    moves = np.asarray(moves, np.int64)             # (L_pad, passes, 2|3)
     iters, last = moves[:lanes, :, 0], moves[:lanes, :, 1]
-    return {"trips": int(moves[:, :, 0].max(axis=0).sum()),
-            "lane_iters": int(iters.sum()),
-            "iters_after_best": int((iters - last).sum())}
+    counts = {"trips": int(moves[:, :, 0].max(axis=0).sum()),
+              "lane_iters": int(iters.sum()),
+              "iters_after_best": int((iters - last).sum())}
+    if moves.shape[2] > 2:
+        counts["pull_overflow"] = int(moves[:lanes, :, 2].sum())
+    return counts
 
 
 def execute_fm_works(works: Sequence[FMWork],

@@ -14,7 +14,7 @@ all passes:
            │ fori_loop over passes:                         │
            │   gain recompute (take-based, O(n·d), local)   │
            │   while_loop moves (select → apply → best)     │
-           │ state (part, pulled0/1, w0, w1, best) resident │
+           │ state (part, pulled, w0, w1, best) resident    │
            └────────────────────────────────────────────────┘
              ▼
     HBM:   bpart[l]  sep_w[l]  imb[l]  moves[l]
@@ -25,12 +25,21 @@ own budget — lanes with small budgets are not serialized behind large
 ones, and ``FMWork.bucket_key`` no longer needs the pow2 ``max_moves``
 sub-bucket (fewer buckets ⇒ fewer compiles, wider lane stacks).
 
+Each move updates the gains ``pulled`` (the ``(2, n)`` weights each
+vertex would pull, per side) in one scatter-add over the flattened
+``2n`` array: v's row, plus the rows of the vertices v pulls into the
+separator.  Where rows are wider than ``PULL_K`` the pulled slots are
+compacted to lists of ``PULL_K`` rows, one scatter round each, so a move
+scatters ``(PULL_K + 1) · d`` addends, not the ``d × d`` block of every
+slot of v's row.
+
 Move counters: each pass's move loop also reports how many moves it ran
-(``iters``) and one past the last move that improved the best state
-(``last_better``, 0 if none did); the moves after it are thrown away by
-the revert to best.  They are computed on every call — one more scalar in
-the loop carry — and returned beside the partitions as ``moves``
-``(L, passes, 2)`` int32.
+(``iters``), one past the last move that improved the best state
+(``last_better``, 0 if none did; the moves after it are thrown away by
+the revert to best) and how many moves took more than one scatter round
+(``pull_overflow``).  They are computed on every call — scalars in the
+loop carry — and returned beside the partitions as ``moves``
+``(L, passes, 3)`` int32.
 
 Bit-parity contract: per-pass tiebreak noise is precomputed outside the
 kernel (``fm_noise``) with the exact op sequence of the hoisted path —
@@ -50,6 +59,9 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -jnp.inf
 BIG_NOISE = 1e9
+#: Width of the pulled list one scatter round of the move loop carries.
+#: Rows of width ``d <= PULL_K`` take the whole row as the list.
+PULL_K = 8
 
 
 def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
@@ -61,21 +73,31 @@ def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
     ``jax.vmap`` in ``core.fm.fm_refine_multi``) and the fused kernel
     (called per grid lane inside ``_fm_fused_kernel``) — one definition,
     so the two paths cannot drift.  Returns the pass's state, then its
-    move counters ``iters`` (moves run) and ``last_better`` (one past the
-    last move that improved the best state, 0 if none did).
+    move counters ``iters`` (moves run), ``last_better`` (one past the
+    last move that improved the best state, 0 if none did) and
+    ``pull_overflow`` (moves whose pulled set took more than one scatter
+    round, i.e. pulled more than ``PULL_K`` slots).
     """
     n, d = nbrs.shape
+    compact = d > PULL_K
 
     def move_cond(carry):
         i, alive, *_ = carry
         return (i < max_moves) & alive
 
     def move_body(carry):
-        """One FM move.  ``pulled0/1`` are maintained incrementally:
-        selection is O(n) vector ops, the update is O(d²) scatters —
-        (beyond-paper optimization vs the naive O(n·d) gain recompute)."""
-        (i, alive, part, moved, pulled0, pulled1,
-         w0, w1, ws, bpart, bws, bimb, last_better) = carry
+        """One FM move.  ``pulled`` = (pulled0, pulled1) is maintained
+        incrementally: selection is O(n) vector ops; the update is one
+        scatter-add into the flattened ``(2n,)`` array of v's row (into
+        ``pulled[1 - side]``) and the rows of the vertices v pulls (out of
+        ``pulled[side]``).  Where ``d > PULL_K`` the pull slots of v's row
+        are compacted (cumsum rank, dense ``(PULL_K, d)`` compare) to
+        ``PULL_K`` rows per round, in ``ceil(pulled slots / PULL_K)``
+        rounds; a duplicated slot stays a row of its own, so every target
+        gets the same addends as a full ``d x d`` update."""
+        (i, alive, part, moved, pulled, w0, w1, ws, bpart, bws, bimb,
+         last_better, overflow) = carry
+        pulled0, pulled1 = pulled[0], pulled[1]
         gain0 = vwgt_f - pulled0
         gain1 = vwgt_f - pulled1
         # --- feasibility (balance after move)
@@ -105,23 +127,45 @@ def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
         tgt_pull = jnp.where(pull_slot, nv, n)
         part = part.at[tgt_pull].set(2, mode="drop")
         part = part.at[v].set(jnp.where(ok, side, part[v]))
-        # pulled0/1 updates from v's side change (v: 2 -> side)
-        tgt_v = jnp.where(nvalid & ok, nv, n)
+        # pulled updates: flat index s * n + x is pulled[s][x]; 2n drops
+        to_v = jnp.where(side == 1, 0, n)       # v: 2 -> side
+        to_u = n - to_v                         # u: 1 - side -> 2
         dv_w = vwgt_f[v]
-        pulled0 = pulled0.at[tgt_v].add(
-            jnp.where(side == 1, dv_w, 0.0), mode="drop")
-        pulled1 = pulled1.at[tgt_v].add(
-            jnp.where(side == 0, dv_w, 0.0), mode="drop")
-        # pulled0/1 updates from the pulled set (u: 1-side -> 2)
-        rows = nbrs[nv]                                     # (d, d)
-        rvalid = valid[nv] & pull_slot[:, None]
-        tgt_u = jnp.where(rvalid, rows, n).reshape(-1)
-        amt = jnp.broadcast_to(vwgt_f[nv][:, None], rows.shape)
-        amt = jnp.where(rvalid, amt, 0.0).reshape(-1)
-        pulled0 = pulled0.at[tgt_u].add(
-            jnp.where(side == 0, -amt, 0.0), mode="drop")
-        pulled1 = pulled1.at[tgt_u].add(
-            jnp.where(side == 1, -amt, 0.0), mode="drop")
+        v_idx = jnp.where(nvalid & ok, to_v + nv, 2 * n)
+        v_amt = jnp.broadcast_to(dv_w, (d,))
+
+        def rows_of(u, has):
+            """Flat targets and addends of the rows of pulled ``u``."""
+            rvalid = valid[u] & has[:, None]
+            return (jnp.where(rvalid, to_u + nbrs[u], 2 * n),
+                    jnp.where(rvalid, -vwgt_f[u][:, None], 0.0))
+
+        def scatter(flat, idx, amt):
+            return flat.at[idx.reshape(-1)].add(amt.reshape(-1), mode="drop")
+
+        n_pull = jnp.sum(pull_slot.astype(jnp.int32))
+        if compact:
+            rank = jnp.cumsum(pull_slot.astype(jnp.int32)) - 1
+            row = jnp.arange(PULL_K, dtype=jnp.int32)[:, None]
+
+            def chunk(r):
+                """Rows of the pull slots ranked r·K .. r·K + K - 1."""
+                sel = pull_slot & (rank == r * PULL_K + row)   # (K, d)
+                u = jnp.sum(jnp.where(sel, nv, 0), axis=1)
+                return rows_of(u, jnp.any(sel, axis=1))
+
+            u_idx, u_amt = chunk(0)
+        else:
+            u_idx, u_amt = rows_of(nv, pull_slot)
+        flat = scatter(pulled.reshape(-1),
+                       jnp.concatenate([v_idx[None], u_idx]),
+                       jnp.concatenate([v_amt[None], u_amt]))
+        if compact:
+            _, flat = jax.lax.while_loop(
+                lambda c: c[0] * PULL_K < n_pull,
+                lambda c: (c[0] + 1, scatter(c[1], *chunk(c[0]))),
+                (jnp.int32(1), flat))
+        pulled = flat.reshape(2, n)
         # weights
         dv = jnp.where(ok, dv_w, 0.0)
         w0 = w0 + jnp.where(side == 0, dv, 0.0) - jnp.where(side == 1, pulled_w, 0.0)
@@ -135,16 +179,18 @@ def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
         bws = jnp.where(better, ws, bws)
         bimb = jnp.where(better, jnp.minimum(imb_new, bimb), bimb)
         last_better = jnp.where(better, i + 1, last_better)
-        return (i + 1, ok, part, moved, pulled0, pulled1,
-                w0, w1, ws, bpart, bws, bimb, last_better)
+        overflow = overflow + (n_pull > PULL_K).astype(jnp.int32)
+        return (i + 1, ok, part, moved, pulled, w0, w1, ws, bpart, bws,
+                bimb, last_better, overflow)
 
     moved = jnp.zeros(n, bool)
-    carry = (jnp.int32(0), jnp.bool_(True), part, moved, pulled0,
-             pulled1, w0, w1, ws, bpart, bws, bimb, jnp.int32(0))
+    carry = (jnp.int32(0), jnp.bool_(True), part, moved,
+             jnp.stack([pulled0, pulled1]), w0, w1, ws, bpart, bws, bimb,
+             jnp.int32(0), jnp.int32(0))
     carry = jax.lax.while_loop(move_cond, move_body, carry)
-    (iters, _, part, _, _, _, w0, w1, ws, bpart, bws, bimb,
-     last_better) = carry
-    return part, w0, w1, ws, bpart, bws, bimb, iters, last_better
+    (iters, _, part, _, _, w0, w1, ws, bpart, bws, bimb,
+     last_better, overflow) = carry
+    return part, w0, w1, ws, bpart, bws, bimb, iters, last_better, overflow
 
 
 def fm_noise(keys, n: int, passes: int) -> jax.Array:
@@ -199,16 +245,16 @@ def _fm_fused_kernel(nbr_ref, vwgt_ref, part_ref, locked_ref, noise_ref,
         wn = jnp.where(valid, wn, 0.0)
         pulled0 = jnp.sum(wn * (pn == 1), axis=1)
         pulled1 = jnp.sum(wn * (pn == 0), axis=1)
-        (part, w0, w1, ws, bpart, bws, bimb, iters, last) = fm_move_loop(
+        (part, w0, w1, ws, bpart, bws, bimb, *counts) = fm_move_loop(
             nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0, pulled1,
             w0, w1, ws, bpart, bws, bimb, noise, pert, max_moves,
             pos_only=pos_only)
-        moves = moves.at[p].set(jnp.stack([iters, last]))
+        moves = moves.at[p].set(jnp.stack(counts))
         part = bpart                           # revert to best
         w0, w1, ws = sums(part)
         return (part, w0, w1, ws, bpart, bws, bimb, moves)
 
-    moves = jnp.zeros((passes, 2), jnp.int32)
+    moves = jnp.zeros((passes, 3), jnp.int32)
     carry = (part, w0, w1, ws, bpart, bws, bimb, moves)
     carry = jax.lax.fori_loop(0, passes, pass_body, carry)
     (part, w0, w1, ws, bpart, bws, bimb, moves) = carry
@@ -255,13 +301,13 @@ def fm_fused_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
             pl.BlockSpec((1, n), lambda l: (l, 0)),
             pl.BlockSpec((1, 1), lambda l: (l, 0)),
             pl.BlockSpec((1, 1), lambda l: (l, 0)),
-            pl.BlockSpec((1, passes, 2), lambda l: (l, 0, 0)),
+            pl.BlockSpec((1, passes, 3), lambda l: (l, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((L, n), jnp.int32),
             jax.ShapeDtypeStruct((L, 1), jnp.float32),
             jax.ShapeDtypeStruct((L, 1), jnp.float32),
-            jax.ShapeDtypeStruct((L, passes, 2), jnp.int32),
+            jax.ShapeDtypeStruct((L, passes, 3), jnp.int32),
         ],
         interpret=interpret,
     )(nbr, vwgt_f, parts_init.astype(jnp.int32),

@@ -62,7 +62,8 @@ def fm_refine_batch(nbr, vwgt, parts_init, locked, keys, eps_frac,
     path's per-pass gain recompute backend.  All modes return
     bit-identical results, ``(parts, sep_w, imb, moves)``, the move
     counters included (asserted in ``tests/test_fm_fused.py`` and
-    ``tests/test_fm_counters.py``).
+    ``tests/test_fm_counters.py``); the oracle's ``moves`` lacks the
+    third counter, ``pull_overflow``, which only the compacted update has.
     """
     if mode is None:
         mode = fm_mode_default()

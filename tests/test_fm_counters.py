@@ -7,7 +7,8 @@ carried on the ``launch`` event, and free of new executables.
 * a band graph's anchor widens its table, and ``slots`` counts only the
   real neighbours;
 * ``execute_fm_works`` puts ``trips``, ``lane_iters``,
-  ``iters_after_best`` and ``slots`` on its ``launch``;
+  ``iters_after_best``, ``slots`` and, but for the oracle,
+  ``pull_overflow`` on its ``launch``;
 * tracing on or off dispatches the same executables;
 * the host steps of nested dissection emit their ``stage`` events.
 """
@@ -28,11 +29,16 @@ from test_fm_fused import (_assert_bit_identical, _rand_lanes,  # noqa: E402
                            _run_all_three)
 
 LAUNCH_KEYS = ("trips", "lane_iters", "iters_after_best", "slots")
+DEVICE_KEYS = LAUNCH_KEYS + ("pull_overflow",)     # not the oracle's
 
 
 def _assert_same_counts(a, b, what):
+    """The counters both sides report: the oracle has ``iters`` and
+    ``last_better``, hoisted and fused ``pull_overflow`` besides."""
     x, y = np.asarray(a[3]), np.asarray(b[3])
-    assert np.array_equal(x, y), f"{what}: moves differ\n{x}\n{y}"
+    k = min(x.shape[-1], y.shape[-1])
+    assert np.array_equal(x[..., :k], y[..., :k]), \
+        f"{what}: moves differ\n{x}\n{y}"
 
 
 class _Events:
@@ -68,11 +74,12 @@ def test_three_paths_count_the_same_moves(L, passes, pos_only):
     _assert_same_counts(fused, hoisted, "fused vs hoisted")
     _assert_same_counts(fused, oracle, "fused vs oracle")
     moves = np.asarray(hoisted[3])
-    assert moves.shape == (L, passes, 2) and moves.dtype == np.int32
-    iters, last = moves[..., 0], moves[..., 1]
+    assert moves.shape == (L, passes, 3) and moves.dtype == np.int32
+    iters, last, overflow = moves[..., 0], moves[..., 1], moves[..., 2]
     mm = np.asarray(args[6])[:, None]
     assert (iters <= mm).all() and (0 <= last).all() and (last <= iters).all()
     assert iters.sum() > 0 and (iters > last).any()
+    assert (overflow == 0).all()            # d = 4: the whole row is a list
 
 
 def test_a_lane_without_moves_counts_no_iteration():
@@ -130,11 +137,15 @@ def test_launch_carries_the_move_counters(mode):
         <= lanes * launch["trips"]
     assert 0 <= launch["iters_after_best"] <= launch["lane_iters"]
     assert launch["slots"] == lanes * int(band.degrees().sum())
+    if mode == "oracle":
+        assert "pull_overflow" not in launch
+    else:
+        assert 0 <= launch["pull_overflow"] <= launch["lane_iters"]
     # the counters read what the program ran: the same on every path
     ref, ev_ref = _collect(lambda: execute_fm_works(works, mode="hoisted"))
     (ref_launch,) = ev_ref.of("launch")
-    assert {k: launch[k] for k in LAUNCH_KEYS} == \
-        {k: ref_launch[k] for k in LAUNCH_KEYS}
+    keys = LAUNCH_KEYS if mode == "oracle" else DEVICE_KEYS
+    assert {k: launch[k] for k in keys} == {k: ref_launch[k] for k in keys}
     for (p, s, i), (q, t, j) in zip(res, ref):
         assert np.array_equal(p, q) and s == t and i == j
 
@@ -160,7 +171,7 @@ def test_tracing_uses_the_same_executables(mode, program):
     assert [p["compile"] for p in ev.of("stage")] == [False]
     assert [s.attrs["compile"] for s in tr.spans
             if s.name == "dispatch:fm"] == [False]
-    assert all(k in ev.of("launch")[0] for k in LAUNCH_KEYS)
+    assert all(k in ev.of("launch")[0] for k in DEVICE_KEYS)
     for (p, s, i), (q, t, j) in zip(plain, out):
         assert np.array_equal(p, q) and s == t and i == j
 

@@ -32,17 +32,19 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.core.fm import (FMWork, execute_fm_works,  # noqa: E402
                            fm_refine_multi, refine_parts)
-from repro.kernels.fm_fused import fm_fused_multi, fm_noise  # noqa: E402
+from repro.kernels.fm_fused import (PULL_K, fm_fused_multi,  # noqa: E402
+                                    fm_noise)
 from repro.kernels.ops import fm_mode_default  # noqa: E402
 from repro.kernels.ref import fm_fused_ref  # noqa: E402
 
 
 def _rand_lanes(seed: int, L: int, n: int, d: int,
-                mixed_budget: bool = True):
-    """A random lane stack: ELL graphs, weights, states, locks, budgets."""
+                mixed_budget: bool = True, drop: float = 0.4):
+    """A random lane stack: ELL graphs, weights, states, locks, budgets.
+    Rows list neighbours with repeats; ``drop`` of the slots are empty."""
     rng = np.random.default_rng(seed)
     nbr = rng.integers(0, n, (L, n, d)).astype(np.int32)
-    nbr[rng.random((L, n, d)) < 0.4] = -1           # ragged rows
+    nbr[rng.random((L, n, d)) < drop] = -1          # ragged rows
     vwgt = rng.integers(1, 4, (L, n)).astype(np.int32)
     part = rng.integers(0, 3, (L, n)).astype(np.int8)
     locked = rng.random((L, n)) < rng.uniform(0.0, 0.3, (L, 1))
@@ -99,6 +101,30 @@ def test_fused_parity_passes_and_pos_only(passes, pos_only):
     tag = f"passes={passes} pos_only={pos_only}"
     _assert_bit_identical(fused, hoisted, f"{tag} fused vs hoisted")
     _assert_bit_identical(fused, oracle, f"{tag} fused vs oracle")
+
+
+@pytest.mark.parametrize("d,n,seed,drop", [(32, 64, 32, 0.1),
+                                           (64, 64, 66, 0.1),
+                                           (PULL_K, 32, 3, 0.0)])
+def test_fused_parity_pulled_rounds(d, n, seed, drop):
+    """Dense rows that repeat neighbours: with ``d > PULL_K`` moves pull
+    more than ``PULL_K`` slots, so the compacted update runs extra scatter
+    rounds; with ``d <= PULL_K`` the whole row is the list and none
+    overflows.  All three implementations stay bit-identical, and the
+    counters the oracle has (``iters``, ``last_better``) agree."""
+    args = _rand_lanes(seed=seed, L=3, n=n, d=d, drop=drop)
+    hoisted, fused, oracle = _run_all_three(args, passes=3, pos_only=False)
+    _assert_bit_identical(fused, hoisted, f"d={d} fused vs hoisted")
+    _assert_bit_identical(fused, oracle, f"d={d} fused vs oracle")
+    moves = np.asarray(fused[3])
+    assert np.array_equal(moves, np.asarray(hoisted[3]))
+    assert np.array_equal(moves[..., :2], np.asarray(oracle[3]))
+    overflow = moves[..., 2]
+    assert (overflow <= moves[..., 0]).all()
+    if d > PULL_K:
+        assert overflow.sum() > 0, "no move took a second scatter round"
+    else:
+        assert (overflow == 0).all()
 
 
 def test_fused_parity_many_seeds_property_sweep():

@@ -24,6 +24,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 L = 8
 #: (n, d) ELL widths: 2-D/3-D 7-point meshes, and 27-point meshes
 WIDTHS = [(4096, 8), (32768, 32)]
+#: (n, d) FM widths of anchored band graphs: an anchor joined to a whole
+#: band layer widens the rows past ``PULL_K`` (the 27-point mesh of 13^3)
+FM_WIDTHS = [(2048, 256), (1024, 128)]
 
 
 @pytest.fixture(scope="module")
@@ -73,7 +76,7 @@ def test_bfs_compiles(one_chip, n, d):
         _sds((L, n), jnp.int32, one_chip), width=3).compile()
 
 
-@pytest.mark.parametrize("n,d", WIDTHS)
+@pytest.mark.parametrize("n,d", WIDTHS + FM_WIDTHS)
 def test_fm_hoisted_jnp_compiles(one_chip, n, d):
     from repro.core.fm import fm_refine_multi
     c = one_chip
