@@ -19,7 +19,13 @@ A run:
    matching ones on exact lane counts, so only the graphs the window will
    order warm every executable it will use.  It stops once it has
    ordered ``HEADROOM`` times what the window would at the rate of its
-   rounds that built no new executable, plus a traced run's tail.
+   rounds that built no new executable, plus a traced run's tail.  Set-up
+   has a budget: what the run's limit leaves once the window, the late
+   wait, the traced tail and the check have had their room
+   (``setup_budget``).  A warm-up that passes it ends the run at once
+   with ``OVER_BUDGET`` and the reason, and prints no result.  At the
+   end of set-up the run logs the lattice of executables the warm-up
+   used (``lattice``).
 2. The window: a fresh ``OrderingService``, the traffic's closed loop for
    ``--seconds``; requests in flight at the close are waited for under
    the same load.  ``--trace 1`` records the service's spans and events
@@ -63,6 +69,19 @@ import spec  # noqa: E402
 #: the warm-up orders this many times the graphs the window is expected
 #: to order at the warm-up's steady rate
 HEADROOM = 1.5
+#: the time a run may take in all, and the time the first run of a cell
+#: in a checkout may take, which compiles (the benchmark's run limits,
+#: which hold per cell: PERF.md section 2)
+RUN_LIMIT_S = 360.0
+FIRST_RUN_LIMIT_S = 1200.0
+#: kept for the check, which orders a sample of 6 with the plain
+#: reference after the window: 2.3-2.4 s each at 13^3, up to 7 s each
+#: on circuits of 2-4k vertices
+CHECK_RESERVE_S = 45.0
+#: the exit code of a run whose set-up passed its budget
+OVER_BUDGET = 5
+#: the launch kinds whose executables the set-up's lattice lists
+LATTICE_KINDS = ("fm", "bfs", "match")
 _T_IMPORT = time.perf_counter()
 
 
@@ -142,6 +161,59 @@ def first_use_report(events: Events, clog: CompileLog, t0: float,
     return out
 
 
+def first_uses_text(fu: dict) -> str:
+    """``first_use_report``'s counts on one line."""
+    return ", ".join(f"{how} {count} ({secs:.3f} s, {secs / count:.4f} s "
+                     f"each)" for how, (count, secs) in sorted(fu.items())) \
+        or "none"
+
+
+def lattice(events) -> dict:
+    """Per launch kind of ``LATTICE_KINDS``, the executables the
+    ``launch`` events among ``events`` used, as ``{(n_pad, d_pad):
+    {lanes_pad: set of executable keys}}``.  An executable is keyed, as
+    the program's own first-use keys are, by its bucket, its rounds (FM
+    passes, BFS width, matching rounds) and its padded lane count."""
+    out = {k: {} for k in LATTICE_KINDS}
+    for _, kind, p in events:
+        if kind == "launch" and p["kind"] in out:
+            b = tuple(p["bucket"])
+            out[p["kind"]].setdefault(b[:2], {}).setdefault(
+                p["lanes_pad"], set()).add((b, p["rounds"]))
+    return out
+
+
+def log_lattice(events) -> None:
+    """One line per launch kind: how many executables, and the distinct
+    ``lanes_pad`` of each ``(n_pad, d_pad)`` bucket."""
+    for kind, buckets in lattice(events).items():
+        count = sum(len(keys) for pads in buckets.values()
+                    for keys in pads.values())
+        pads = "; ".join(f"{n}x{d}: " + ",".join(map(str, sorted(lp)))
+                         for (n, d), lp in sorted(buckets.items()))
+        log(f"lattice {kind}: {count} executables; lanes_pad by n_pad x "
+            f"d_pad: {pads or '-'}")
+
+
+def setup_budget(limit: float, seconds: float, round_s) -> float:
+    """The set-up seconds a run may spend within ``limit``: what is left
+    once the window (``seconds``), the late wait and the traced tail
+    (twice the longest steady round ``round_s``, each at most
+    ``loop.LATE_S``, and ``LATE_S`` each while no round was steady) and
+    the check (``CHECK_RESERVE_S``) have had their room."""
+    late = loop.LATE_S if round_s is None else min(round_s, loop.LATE_S)
+    return limit - seconds - 2 * late - CHECK_RESERVE_S
+
+
+class OverBudget(Exception):
+    """The warm-up passed the set-up budget."""
+
+    def __init__(self, age: float, budget: float, done: int):
+        super().__init__(f"set-up {age:.3f} s passed its budget of "
+                         f"{budget:.3f} s")
+        self.age, self.done = age, done
+
+
 class Graphs:
     """The program's graph of each pool request, made on first use and
     kept, so the window orders the objects the warm-up made."""
@@ -167,6 +239,7 @@ class WarmUp:
         self.seconds, self.extra = seconds, extra
         self.mark = (0.0, 0, 0)             # time, answers, first uses
         self.steady_s, self.steady_n = 0.0, 0
+        self.longest = None                 # the longest steady round, s
         self.need = None
 
     def firsts(self) -> int:
@@ -180,6 +253,7 @@ class WarmUp:
         f = self.firsts()
         self.mark = (t, done, f)
         if f == f0:
+            self.longest = max(self.longest or 0.0, t - t0)
             self.steady_s += t - t0
             self.steady_n += done - d0
             self.need = self.clients * self.extra + math.ceil(
@@ -232,10 +306,24 @@ def device_info(jax, chips: int) -> dict:
             "count": len(devs), "memory_peak_bytes": peak}
 
 
+def first_run(cache_dir: str, cell: str) -> bool:
+    """Whether this is the first run of ``cell`` with ``cache_dir``: no
+    run of the cell has left its mark there.  Leaves the mark at the
+    run's start, so that every later run of the cell there is held to
+    the shorter limit, as a run limit per cell holds it, however this
+    run ends.  The mark lives in the compile cache, so a cache emptied
+    makes the next run a first one again."""
+    mark = os.path.join(cache_dir, "chipbench", cell)
+    first = not os.path.exists(mark)
+    os.makedirs(os.path.dirname(mark), exist_ok=True)
+    open(mark, "a").close()
+    return first
+
+
 def main(argv=None, root: str = ROOT, require_chip: bool = True) -> int:
     """Run one cell; returns the exit code.  ``require_chip=False``
-    skips the look for a TPU (the tests drive the rest of a run on the
-    CPU with it)."""
+    skips the look for a TPU and leaves the process's compile cache as
+    it is (the tests drive the rest of a run on the CPU with it)."""
     args = parse(argv)
     try:
         cell = spec.load_cell(root, args.workload)
@@ -248,9 +336,9 @@ def main(argv=None, root: str = ROOT, require_chip: bool = True) -> int:
         return 2
     if src not in sys.path:
         sys.path.insert(0, src)
+    cache_dir = os.path.join(root, ".jax_cache")
     if require_chip:
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
-            root, ".jax_cache")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     import jax
     devs = jax.devices()
     if require_chip and (devs[0].platform != "tpu"
@@ -268,10 +356,13 @@ def main(argv=None, root: str = ROOT, require_chip: bool = True) -> int:
     from repro.kernels.ops import fm_mode_default
     from repro.service import OrderingService
 
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or util.CACHE_DIR
     if require_chip:
         util.enable_compile_cache()
-    log(f"cache: {cache_dir} holds {dir_bytes(cache_dir)} bytes at start")
+    first = first_run(cache_dir, cell.name)
+    limit = FIRST_RUN_LIMIT_S if first else RUN_LIMIT_S
+    log(f"cache: {cache_dir} holds {dir_bytes(cache_dir)} bytes at start; "
+        f"{'the first' if first else 'a later'} run of {cell.name} with "
+        f"it, so the run's limit is {limit:.0f} s")
     log(f"device: {devs[0].platform} {devs[0].device_kind} x{len(devs)}; "
         f"paths fm={fm_mode_default()} gain={gain_mode_default()} "
         f"bfs={bfs_mode_default()}")
@@ -284,29 +375,51 @@ def main(argv=None, root: str = ROOT, require_chip: bool = True) -> int:
     events, clog = Events(), CompileLog()
     obs.register_collector(events)
     jax.monitoring.register_event_listener(clog.on_event)
-    trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
     try:
         t_warm = time.perf_counter()
         warm_close = WarmUp(events, clients, args.seconds, args.trace)
-        step = {"next": 0}
+        step = {"next": 0, "tightest": 0.0}
 
         def progress(t: float, done: int) -> None:
+            age = process_age()
+            budget = setup_budget(limit, args.seconds, warm_close.longest)
+            if age > budget:
+                raise OverBudget(age, budget, done)
+            step["tightest"] = max(step["tightest"], age / budget)
             if done >= step["next"]:
                 step["next"] = done + max(clients, 5)
                 log(f"warm-up: {done} answered, {t - t_warm:.1f} s, "
-                    f"{warm_close.firsts()} first uses so far")
+                    f"{warm_close.firsts()} first uses so far, set-up "
+                    f"{age:.1f} s of a budget of {budget:.1f} s")
 
-        warm = loop.closed_loop(OrderingService(), pool, graphs, clients,
-                                nproc, close=warm_close, on_pump=progress)
+        try:
+            warm = loop.closed_loop(OrderingService(), pool, graphs,
+                                    clients, nproc, close=warm_close,
+                                    on_pump=progress)
+        except OverBudget as over:
+            fu = first_use_report(events, clog, t_warm, time.perf_counter())
+            log_lattice(events.events)
+            log(f"error: {over}, before the window could open; the run's "
+                f"limit is {limit:.0f} s")
+            log(f"set-up seconds: {over.age:.3f}")
+            log(f"first uses so far: {first_uses_text(fu)}")
+            log(f"answers so far: {over.done}")
+            log("steady round: " + (
+                "none" if warm_close.longest is None else
+                f"yes, the longest {warm_close.longest:.3f} s"))
+            return OVER_BUDGET
         fu = first_use_report(events, clog, t_warm, time.perf_counter())
         log(f"warm-up: {len(warm.records)} requests, "
             f"{sum(r.status == 'ok' for r in warm.records)} answered, "
             f"{time.perf_counter() - t_warm:.3f} s, steady rate "
             f"{warm_close.steady_n / max(warm_close.steady_s, 1e-9):.4f} "
             f"orderings/s, aimed at {warm_close.need}")
-        for how, (count, secs) in sorted(fu.items()):
-            log(f"first uses in set-up, {how}: {count}, {secs:.3f} s, "
-                f"{secs / count:.4f} s each")
+        log(f"first uses in set-up: {first_uses_text(fu)}")
+        log_lattice(events.events)
+        log(f"set-up budget: {process_age():.3f} s of "
+            f"{setup_budget(limit, args.seconds, warm_close.longest):.3f} s "
+            f"at the warm-up's end; at most {100 * step['tightest']:.2f}% "
+            f"of it used at any pump")
         if warm_close.need is None:
             log("warning: the warm-up ended before a round built nothing "
                 "new; the window may meet first uses")
@@ -314,6 +427,7 @@ def main(argv=None, root: str = ROOT, require_chip: bool = True) -> int:
         gc.collect()
 
         svc = OrderingService()
+        trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
         prof = {"stack": contextlib.ExitStack()}
 
         def on_tail(what: str, t: float) -> None:

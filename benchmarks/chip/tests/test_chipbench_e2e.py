@@ -9,9 +9,12 @@ with files alone, as a later change would.
 import importlib.util
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
+import time
+import types
 
 import numpy as np
 import pytest
@@ -74,6 +77,14 @@ def run_cell(root, cell, capsys, seed=11, seconds=1.0, trace=0):
                   require_chip=False)
     out = capsys.readouterr().out.strip().splitlines()
     return rc, json.loads(out[-1]) if rc == 0 else None
+
+
+@pytest.fixture(autouse=True)
+def run_age(monkeypatch):
+    """A run driven in this process counts its set-up from its test's
+    start, not from the test process's start."""
+    t0 = time.perf_counter()
+    monkeypatch.setattr(run, "process_age", lambda: time.perf_counter() - t0)
 
 
 @pytest.fixture(scope="module")
@@ -206,3 +217,114 @@ def test_benchmark_files_alone_no_result(tmp_path):
          "fe3d27.solo", "--seed", "1", "--seconds", "1", "--trace", "0"],
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300)
     assert proc.returncode != 0 and not _result_lines(proc)
+
+
+class _NeverSteady:
+    """A service whose every answer pays a first use of a new FM
+    executable (another ``lanes_pad`` each time): no round is steady."""
+
+    PUMP_S = 0.1
+
+    def __init__(self):
+        self.queue, self.next, self.pumps = {}, 0, []
+
+    def submit(self, graph, seed, nproc):
+        self.next += 1
+        self.queue[self.next] = graph
+        return self.next
+
+    def poll(self, rid):
+        return None
+
+    def queue_depth(self):
+        return len(self.queue)
+
+    def pump(self):
+        from repro import obs
+        self.pumps.append(time.perf_counter())
+        time.sleep(self.PUMP_S)
+        rid = min(self.queue)
+        g = self.queue.pop(rid)
+        obs.emit("stage", {"name": "fm", "seconds": self.PUMP_S,
+                           "compile": True})
+        obs.emit("launch", {"kind": "fm", "lanes": 1, "lanes_pad": 8 * rid,
+                            "bucket": (128, 16, 3, False), "rounds": 3})
+        return {rid: types.SimpleNamespace(status="ok", perm=np.arange(g.n))}
+
+
+def test_setup_over_budget_exits_5_with_the_reason(tiny_root, capsys,
+                                                   monkeypatch):
+    """A warm-up that never has a steady round stops within one pump of
+    its budget, with code 5, no result and the reason as its last
+    lines; the budget keeps ``LATE_S`` twice while no round was
+    steady."""
+    import repro.service
+    made = []
+
+    def service():
+        made.append(_NeverSteady())
+        return made[-1]
+    monkeypatch.setattr(repro.service, "OrderingService", service)
+    monkeypatch.setattr(run, "RUN_LIMIT_S", 5.0)
+    monkeypatch.setattr(run, "FIRST_RUN_LIMIT_S", 5.0)
+    monkeypatch.setattr(run, "CHECK_RESERVE_S", 0.5)
+    monkeypatch.setattr(loop, "LATE_S", 0.25)
+    budget = 5.0 - 1.0 - 2 * 0.25 - 0.5
+    rc = run.main(["--workload", "tinymesh.solo", "--seed", "5",
+                   "--seconds", "1", "--trace", "0"], root=tiny_root,
+                  require_chip=False)
+    out, err = capsys.readouterr()
+    assert rc == run.OVER_BUDGET == 5
+    assert not [ln for ln in out.splitlines() if ln.startswith("{")]
+    last = err.strip().splitlines()[-5:]
+    assert last[0].startswith("error: set-up ")
+    assert float(re.search(r"budget of ([0-9.]+) s", last[0]).group(1)) \
+        == pytest.approx(budget, abs=1e-3)
+    age = float(last[1].split(": ")[1])
+    pumps = made[0].pumps
+    one_pump = max(b - a for a, b in zip(pumps, pumps[1:]))
+    assert budget < age <= budget + one_pump + 0.1
+    answers = int(last[3].split(": ")[1])
+    assert answers == len(pumps) > 0
+    assert last[2] == "first uses so far: traced " \
+        f"{answers} ({answers * _NeverSteady.PUMP_S:.3f} s, " \
+        f"{_NeverSteady.PUMP_S:.4f} s each)"
+    assert last[4] == "steady round: none"
+    assert f"lattice fm: {answers} executables" in err
+
+
+def test_budget_leaves_a_small_cell_alone(tiny_root, capsys):
+    """A cell whose set-up fits runs to its result, and its log lists the
+    lattice of each launch kind and the budget it stayed under."""
+    rc = run.main(["--workload", "tinymesh.solo", "--seed", "12",
+                   "--seconds", "1", "--trace", "0"], root=tiny_root,
+                  require_chip=False)
+    out, err = capsys.readouterr()
+    assert rc == 0 and json.loads(out.splitlines()[-1])["correct"]
+    for kind in run.LATTICE_KINDS:
+        assert re.search(rf"^lattice {kind}: [1-9][0-9]* executables; "
+                         r"lanes_pad by n_pad x d_pad: \d+x\d+: \d",
+                         err, re.M), kind
+    used, budget = map(float, re.search(
+        r"set-up budget: ([0-9.]+) s of ([0-9.]+) s", err).groups())
+    assert 0 < used < budget
+
+
+def test_first_run_of_a_cell_gets_the_longer_limit(tmp_path, capsys,
+                                                   monkeypatch):
+    """With an empty cache directory the run is the cell's first there
+    and is held to ``FIRST_RUN_LIMIT_S``; the next run of the cell is
+    held to ``RUN_LIMIT_S``."""
+    root = make_root(tmp_path, {"tinymesh.solo": ("tinymesh", "tsolo")},
+                     {"tinymesh": TINY_MESH}, {"tsolo": SOLO})
+    monkeypatch.setattr(run, "RUN_LIMIT_S", 1.0)
+    argv = ["--workload", "tinymesh.solo", "--seed", "14", "--seconds",
+            "1", "--trace", "0"]
+    assert run.main(argv, root=root, require_chip=False) == 0
+    err = capsys.readouterr().err
+    assert "the first run of tinymesh.solo with it, so the run's limit " \
+        "is 1200 s" in err
+    assert run.main(argv, root=root, require_chip=False) == 5
+    err = capsys.readouterr().err
+    assert "a later run of tinymesh.solo with it, so the run's limit is " \
+        "1 s" in err

@@ -213,11 +213,42 @@ def test_warm_up_stops_after_a_steady_round_covering_the_window():
     Ev.events.append((0.0, "stage", {"compile": True}))
     assert not w(2.0, 2)
     assert w.need is None
+    assert w.longest is None            # no steady round timed yet
     assert not w(3.0, 3)                # steady: 1 per s -> 15 + 1
-    assert w.need == 16
+    assert w.need == 16 and w.longest == 1.0
     assert not w(15.0, 15)
     Ev.events.append((0.0, "stage", {"compile": True}))
     assert w(16.0, 16)                  # the window's graphs are warmed
+
+
+def test_setup_budget_leaves_room_for_window_late_wait_tail_and_check():
+    run = _run_module()
+    late = loop.LATE_S
+    assert run.setup_budget(360.0, 51.0, 3.0) == 360 - 51 - 6 - 45
+    assert run.setup_budget(360.0, 51.0, None) == 360 - 51 - 2 * late - 45
+    assert run.setup_budget(1200.0, 51.0, 2 * late) == \
+        1200 - 51 - 2 * late - 45
+
+
+def test_lattice_counts_executables_and_lane_pads():
+    run = _run_module()
+
+    def launch(kind, bucket, rounds, lanes_pad):
+        return (0.0, "launch", {"kind": kind, "bucket": bucket,
+                                "rounds": rounds, "lanes_pad": lanes_pad})
+    events = [launch("fm", (2048, 256, 3, False), 3, 8),
+              launch("fm", (2048, 256, 3, False), 3, 8),     # the same
+              launch("fm", (2048, 256, 3, True), 3, 8),      # pos_only
+              launch("fm", (2048, 256, 3, False), 3, 16),
+              launch("bfs", (1024, 32), 3, 5),
+              launch("bfs", (1024, 32), 4, 5),               # width
+              launch("dbfs", (1024, 32), 3, 5),              # not listed
+              (0.0, "stage", {"compile": True})]
+    lat = run.lattice(events)
+    assert set(lat) == {"fm", "bfs", "match"} and lat["match"] == {}
+    assert {lp: len(k) for lp, k in lat["fm"][(2048, 256)].items()} == \
+        {8: 2, 16: 1}
+    assert {lp: len(k) for lp, k in lat["bfs"][(1024, 32)].items()} == {5: 2}
 
 
 SYNTH = {
