@@ -1,15 +1,19 @@
 """Readings of the comparison's controls at a cell's own size.
 
     python3 benchmarks/chip/control.py --workload <cell> --seeds 1 2 3
+    python3 benchmarks/chip/control.py --config <file> --seeds 1 2 3
 
-For each seed, the first ``check.sample`` graphs of the cell's pool in
-the seed's order are ordered by each control put in the program's place:
-``bfloat16``, the plain reference with its balance sums and gains one
-precision below the configuration's float32 (every addition rounded),
-and ``separators_first``, the reference with each separator eliminated
+``--config`` reads a configuration file that no cell names yet, so its
+limits can be set before its cell is added.  For each seed, the first
+``check.sample`` graphs of the pool in the seed's order are ordered by
+each control put in the program's place: ``bfloat16``, the plain
+reference with its balance sums and gains one precision below the
+configuration's float32 (every addition rounded), and
+``separators_first``, the reference with each separator eliminated
 before the parts it separates.  Prints one JSON line per seed and
-control with the worst reading of each compared number beside its
-limit.  The benchmark's own runs do not run this.
+control with the worst reading of each compared number beside its limit,
+and of each other reading as not compared.  The benchmark's own runs do
+not run this.
 """
 from __future__ import annotations
 
@@ -30,29 +34,37 @@ CONTROLS = ("bfloat16", "separators_first")
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--workload", required=True)
+    which = ap.add_mutually_exclusive_group(required=True)
+    which.add_argument("--workload", help="a cell of BENCHMARK.json")
+    which.add_argument("--config", help="a configuration file")
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     args = ap.parse_args(argv)
-    cell = spec.load_cell(os.path.dirname(os.path.dirname(HERE)),
-                          args.workload)
-    limits = cell.config["check"]
+    if args.config:
+        with open(args.config) as f:
+            config = json.load(f)
+        label = {"config": args.config}
+    else:
+        config = spec.load_cell(os.path.dirname(os.path.dirname(HERE)),
+                                args.workload).config
+        label = {"workload": args.workload}
+    limits = config["check"]
     k = int(limits["sample"])
     for seed in args.seeds:
-        graphs = pool_mod.build_pool(cell.config, seed)[:k]
+        graphs = pool_mod.build_pool(config, seed)[:k]
         for control in CONTROLS:
-            worst = {}
+            worst = dict.fromkeys(check.READINGS, 0.0)
             for req in graphs:
                 got = check.readings(req.n, req.edges, None, control)
                 for name, v in got.items():
-                    if name in limits:
-                        worst[name] = max(worst.get(name, 0.0), v)
+                    worst[name] = max(worst[name], v)
             print(json.dumps({
-                "workload": args.workload, "seed": seed,
-                "control": control,
+                **label, "seed": seed, "control": control,
                 "numbers": {name: [v, limits[name]]
-                            for name, v in worst.items()},
-                "fails": any(v > limits[name]
-                             for name, v in worst.items())}), flush=True)
+                            for name, v in worst.items() if name in limits},
+                "not_compared": {name: v for name, v in worst.items()
+                                 if name not in limits},
+                "fails": any(v > limits[name] for name, v in worst.items()
+                             if name in limits)}), flush=True)
     return 0
 
 

@@ -26,6 +26,8 @@ import numpy as np
 LEAF_SIZE = 96
 FM_PASSES = 3
 EPS = 0.12
+#: a side of the top split holds at least n / PIECE of the n vertices
+PIECE = 200
 
 
 def csr(n: int, edges: np.ndarray):
@@ -204,6 +206,65 @@ def top_imbalance(xadj, adjncy, perm) -> float:
     w0 = max(below, default=0)
     w1 = n - chain - w0
     return abs(w0 - w1) / n
+
+
+def split_imbalance(xadj, adjncy, perm) -> float:
+    """Imbalance of the top split that ``perm`` implies, read from the
+    order itself.
+
+    Dissection orders one part, then the other, then the separator, and
+    no edge joins the two parts.  The reading takes the shortest tail of
+    ``perm``, of at most ``n / 2`` vertices, whose removal leaves a
+    prefix that some position cuts into two sides with no edge between
+    them, and is ``|w0 - w1| / n`` at the most balanced such cut.  A
+    part may hold several components, ordered one after another, so the
+    cut between the parts is not the only one.  A side must hold at
+    least ``n / PIECE`` vertices: a smaller one is a piece that a few
+    separator vertices cut off whole, such as a vertex whose neighbours
+    all lie in the separator, and where those few come last they free it
+    before the rest of the separator frees the parts.  With no such tail
+    it reads 1.0.
+
+    ``top_imbalance`` reads the same where the separator's vertices form
+    one chain in the elimination tree.  Where a separator vertex has no
+    neighbour among the vertices ordered before it but later separator
+    vertices, as FM leaves on circuits, the chain branches inside the
+    separator and ``top_imbalance`` counts nearly every vertex as one
+    side; this reading does not depend on the tree.
+    """
+    xadj = np.asarray(xadj, dtype=np.int64)
+    adjncy = np.asarray(adjncy, dtype=np.int64)
+    n = len(xadj) - 1
+    if n == 0:
+        return 0.0
+    iperm = np.empty(n, dtype=np.int64)
+    iperm[np.asarray(perm, dtype=np.int64)] = np.arange(n)
+    # lo[j]: the earliest position joined by an edge to position j
+    lo = np.arange(n)
+    np.minimum.at(lo, iperm[np.repeat(np.arange(n), np.diff(xadj))],
+                  iperm[adjncy])
+    # reach[t]: the smallest j whose edge (lo[j], j) crosses the cut
+    # before position t (n where none does): the cut is free in every
+    # prefix of at most reach[t] positions
+    reach = np.full(n, n, dtype=np.int64)
+    for j in range(n - 1, -1, -1):      # the smallest j is written last
+        reach[lo[j] + 1:j + 1] = j
+
+    def sides_hold(t, L):
+        return PIECE * np.minimum(t, L - t) >= n
+
+    # cut t counts in a prefix of L positions where it is free there
+    # (t < L <= reach[t]), the tail n - L is at most n / 2, and both
+    # sides hold their share of n: the longest such L is reach[t]
+    cuts = np.arange(1, n)
+    cuts = cuts[(reach[cuts] > cuts) & (n - reach[cuts] <= n // 2)
+                & sides_hold(cuts, reach[cuts])]
+    if not len(cuts):
+        return 1.0
+    longest = int(reach[cuts].max())
+    at = np.arange(1, longest)
+    at = at[(reach[at] >= longest) & sides_hold(at, longest)]
+    return float(np.abs(2 * at - longest).min()) / n
 
 
 # ------------------------------------------------------------------ #

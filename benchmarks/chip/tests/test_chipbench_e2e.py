@@ -193,6 +193,78 @@ def test_control_fails_the_limit():
         assert first["opc_ratio"] > limits["opc_ratio"]
 
 
+#: circuits as a circuit configuration would send them (degree at most
+#: 14-16, mean 6.6), with the limits proposed for its check; below about
+#: 2,000 vertices the bfloat16 control keeps its balance, since its sums
+#: of unit weights are exact to 256
+CIRCUIT = {"name": "circuit", "generator": "circuit", "n_min": 2048,
+           "n_max": 2400, "fanout": 2.4, "nproc": 4, "set_seed": 7,
+           "set_size": 8, "set_block": 4,
+           "check": {"sample": 1, "opc_ratio": 2.0, "split_imbalance": 0.35}}
+
+
+def test_control_fails_the_limit_on_circuits():
+    """On circuits the reference reads its top split from the order
+    within FM's tolerance, the reference in bfloat16 reads over the
+    ``split_imbalance`` limit, and separators first over ``opc_ratio``."""
+    import pool
+    import reference
+    limits = CIRCUIT["check"]
+    for req in pool.build_set(CIRCUIT, 5, 2):
+        xadj, adjncy = reference.csr(req.n, req.edges)
+        assert reference.split_imbalance(
+            xadj, adjncy, reference.nested_dissection(xadj, adjncy)) <= 0.12
+        low = check.readings(req.n, req.edges, None, "bfloat16")
+        assert low["split_imbalance"] > limits["split_imbalance"]
+        first = check.readings(req.n, req.edges, None, "separators_first")
+        assert first["opc_ratio"] > limits["opc_ratio"]
+
+
+def test_control_reads_a_configuration_file(tmp_path, capsys):
+    """``control.py --config`` reads a configuration that no cell names:
+    each control's worst readings beside the limits its ``check`` gives,
+    the other readings as not compared."""
+    control = _load("control")
+    path = tmp_path / "circuit.json"
+    path.write_text(json.dumps(CIRCUIT))
+    assert control.main(["--config", str(path), "--seeds", "3"]) == 0
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert [ln["control"] for ln in lines] == list(control.CONTROLS)
+    for ln in lines:
+        assert ln["config"] == str(path) and ln["seed"] == 3
+        assert set(ln["numbers"]) == {"opc_ratio", "split_imbalance"}
+        assert set(ln["not_compared"]) == {"top_imbalance"}
+        assert ln["fails"] is True
+    with pytest.raises(SystemExit):
+        control.main(["--workload", "fe3d27.solo", "--config", str(path),
+                      "--seeds", "3"])
+
+
+def test_check_logs_the_readings_it_does_not_compare(tiny_root, capsys):
+    """A configuration whose ``check`` gives ``split_imbalance`` no limit
+    compares what it did before; the log gives every reading of each
+    sampled graph, and the worst ``split_imbalance`` as not compared,
+    before the compared numbers that end it."""
+    rc = run.main(["--workload", "tinymesh.solo", "--seed", "13",
+                   "--seconds", "1", "--trace", "0"], root=tiny_root,
+                  require_chip=False)
+    out, err = capsys.readouterr()
+    res = json.loads(out.splitlines()[-1])
+    assert rc == 0 and res["correct"] is True
+    assert list(res["check"]) == ["unresolved", "failed", "invalid_perm",
+                                  "opc_ratio", "top_imbalance"]
+    lines = err.strip().splitlines()
+    sampled = [ln for ln in lines if ln.startswith("check sample ")]
+    assert len(sampled) == TINY_MESH["check"]["sample"]
+    for ln in sampled:
+        assert all(f"{name} " in ln for name in check.READINGS)
+    apart = [i for i, ln in enumerate(lines) if re.fullmatch(
+        r"check split_imbalance: [0-9.e-]+ \(not compared\)", ln)]
+    assert len(apart) == 1 and apart[0] < len(lines) - 5
+    assert [ln.split(":")[0] for ln in lines[-5:]] == [
+        f"check {name}" for name in res["check"]]
+
+
 def _result_lines(proc):
     return [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
 

@@ -113,6 +113,77 @@ def test_top_imbalance_reads_the_top_separator_from_the_tree():
         "top_imbalance"] > 0.5
 
 
+@pytest.mark.parametrize("perm,want", [
+    ([0, 1, 2, 4, 5, 6, 3], 0.0),         # separator {3} last: balanced
+    ([0, 2, 3, 4, 5, 6, 1], 4 / 7),       # separator {1} last
+])
+def test_split_imbalance_reads_the_path_cases_as_the_tree_does(perm, want):
+    xadj, adjncy = reference.csr(7, np.stack([np.arange(6),
+                                              np.arange(1, 7)], 1))
+    assert reference.split_imbalance(xadj, adjncy, perm) == \
+        reference.top_imbalance(xadj, adjncy, perm) == pytest.approx(want)
+
+
+def _two_paths(k, extra):
+    """Parts 0..k-1 and k..2k-1, two paths, then the vertices ``extra``
+    adds: edges ``(n, 2)`` among ids from 2k on and the path ends."""
+    a, b = np.arange(k), np.arange(k, 2 * k)
+    e = np.concatenate([np.stack([a[:-1], a[1:]], 1),
+                        np.stack([b[:-1], b[1:]], 1), extra])
+    return reference.csr(int(e.max()) + 1, e)
+
+
+def test_split_imbalance_where_the_tree_branches_inside_the_separator():
+    """Separator 41 joins the two parts; separator vertex 40 hangs off
+    separator vertex 42 alone, so the elimination tree branches at its
+    root into {40} and the rest, and the tree reads nearly all vertices
+    as one side.  The order reads the parts."""
+    xadj, adjncy = _two_paths(20, [[19, 41], [20, 41], [41, 42], [40, 42]])
+    perm = np.arange(43)
+    assert reference.top_imbalance(xadj, adjncy, perm) == \
+        pytest.approx(40 / 43)
+    assert reference.split_imbalance(xadj, adjncy, perm) == \
+        pytest.approx(1 / 43)
+
+
+def test_split_imbalance_leaves_out_a_piece_the_separator_cuts_off():
+    """Vertex 302 hangs off separator vertex 301 alone and is ordered
+    first: the last separator vertex frees it before the separator frees
+    the parts.  One vertex of 303 is under ``n / PIECE``, no side."""
+    xadj, adjncy = _two_paths(150, [[149, 300], [150, 300], [0, 301],
+                                    [299, 301], [301, 302]])
+    perm = np.concatenate([[302], np.arange(302)])
+    assert reference.split_imbalance(xadj, adjncy, perm) == \
+        pytest.approx(1 / 303)
+    assert reference.top_imbalance(xadj, adjncy, perm) > 0.95
+
+
+def test_split_imbalance_reads_1_with_no_separator_tail():
+    # a path in its own order: every prefix up to half of it is connected
+    xadj, adjncy = reference.csr(7, np.stack([np.arange(6),
+                                              np.arange(1, 7)], 1))
+    assert reference.split_imbalance(xadj, adjncy, np.arange(7)) == 1.0
+    # the tree reads the whole path as one chain of separators
+    assert reference.top_imbalance(xadj, adjncy, np.arange(7)) == 0.0
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("seed", [5, 6])
+@pytest.mark.parametrize("size", [9, 10])
+def test_split_imbalance_equals_top_imbalance_on_grids(size, seed, dtype):
+    """On the 27-point grids of ``fe3d27``'s family the separator is one
+    chain in the tree, and both readings agree to the last digit, in the
+    reference's float32 and in the bfloat16 control."""
+    import ml_dtypes
+    req = pool.build_set(dict(MESH, nx=size, ny=size, nz=size), seed, 1)[0]
+    xadj, adjncy = reference.csr(req.n, req.edges)
+    perm = reference.nested_dissection(
+        xadj, adjncy, dtype=np.float32 if dtype == "float32"
+        else ml_dtypes.bfloat16)
+    assert reference.split_imbalance(xadj, adjncy, perm) == \
+        reference.top_imbalance(xadj, adjncy, perm)
+
+
 def test_is_permutation_rejects_repeats_and_range():
     assert reference.is_permutation(np.array([2, 0, 1]), 3)
     assert not reference.is_permutation(np.array([0, 0, 1]), 3)
