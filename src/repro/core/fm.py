@@ -26,13 +26,13 @@ import dataclasses
 import functools
 import os
 from collections import defaultdict
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.fm_fused import fm_move_loop as _fm_pass
+from repro.kernels.fm_fused import SIDE_K, fm_move_loop as _fm_pass
 from repro.util import pow2 as _pow2    # shared bucketing: one definition
 
 NEG_INF = -jnp.inf
@@ -106,14 +106,16 @@ def gain_mode_default() -> str:
 @functools.partial(jax.jit, static_argnames=("passes", "pos_only",
                                              "gain_mode"))
 def fm_refine_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
-                    max_moves, n_pert, passes: int = 3,
+                    max_moves, n_pert, side_id, side_cnt, passes: int = 3,
                     pos_only: bool = False, gain_mode: str = "jnp"):
     """FM over a flat lane axis: any mix of (graph, instance) pairs.
 
     Shapes (L = lanes): nbr (L, n, d) int32; vwgt (L, n); parts_init
     (L, n) int8; locked (L, n) bool; keys (L, 2) uint32; eps_frac (L,)
-    f32; max_moves, n_pert (L,) int32.  Returns (parts, sep_w, imb,
-    moves) with leading lane axis; ``moves`` (L, passes, 3) int32 holds
+    f32; max_moves, n_pert (L,) int32; side_id (L, SIDE_K) int32 and
+    side_cnt (L, SIDE_K, n) f32, the locked rows left out of ``nbr``
+    (``kernels.fm_fused``; ``n`` and zeros for none).  Returns (parts,
+    sep_w, imb, moves) with leading lane axis; ``moves`` (L, passes, 3) int32 holds
     each pass's move counters (``fm_move_loop``).  The pass loop is
     hoisted out of the per-lane body so the O(L·n·d) gain recompute runs
     as ONE batched kernel per pass.
@@ -149,10 +151,10 @@ def fm_refine_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
         # per-pass tiebreak noise (moved-locks make per-move noise redundant)
         noise = jax.vmap(lambda k: jax.random.uniform(k, (2, n)))(subs)
         pulled0, pulled1 = _pulled_all(nbrs, valid, vwgt_f, part, gain_mode)
-        (part, w0, w1, ws, bpart, bws, bimb, *counts) = jax.vmap(
-            pass_fn)(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
-                     pulled1, w0, w1, ws, bpart, bws, bimb, noise, pert,
-                     max_moves)
+        (part, w0, w1, ws, bpart, bws, bimb, _, *counts) = jax.vmap(
+            pass_fn)(nbrs, valid, vwgt_f, locked, side_id, side_cnt, eps_abs,
+                     part, pulled0, pulled1, w0, w1, ws, bpart, bws, bimb,
+                     noise, pert, max_moves)
         moves.append(jnp.stack(counts, axis=1))             # (L, 3)
         part = bpart                                        # revert to best
         w0, w1, ws = sums(part)
@@ -163,6 +165,13 @@ def fm_refine_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
 # --------------------------------------------------------------------- #
 # host work descriptors + bucketed executor
 # --------------------------------------------------------------------- #
+class _Split(NamedTuple):
+    """A work's side rows, and the table that stays."""
+    rows: np.ndarray                    # the side rows' ids, widest first
+    width: int                          # d_pad of the rows that stay
+    slots: int                          # their real neighbour slots
+
+
 @dataclasses.dataclass
 class FMWork:
     """One multi-instance FM refinement request (unpadded host arrays).
@@ -182,6 +191,13 @@ class FMWork:
     cannot be *selected* for a move, but a move may still *pull* it into
     the separator; schedulers that lock remote-owned copies must
     propagate such pulls themselves.
+
+    Side rows: the at most ``SIDE_K`` widest locked rows that are each
+    wider than ``pow2(max degree of the rows that stay, 8)`` (a band's
+    anchors, wired to a whole band layer) leave the padded table, so
+    ``bucket_key``'s ``d_pad`` is the width of the rows that stay; the
+    device carries them as dense count vectors (``kernels.fm_fused``).
+    The split is read from the locks and degrees alone.
     """
     nbr: np.ndarray                     # (n, d) int32 ELL ids, -1 pad
     vwgt: np.ndarray                    # (n,) vertex weights
@@ -207,8 +223,22 @@ class FMWork:
             max_moves = 2 * sep_sz + 16
         return min(int(max_moves), n_pad, 4096)
 
+    @functools.cached_property
+    def split(self) -> _Split:
+        deg = np.count_nonzero(self.nbr >= 0, axis=1)
+        locked = np.flatnonzero(self.locked)
+        wide = locked[np.argsort(-deg[locked], kind="stable")[:SIDE_K]]
+        for k in range(len(wide), 0, -1):
+            rest = deg.copy()
+            rest[wide[:k]] = -1
+            width = _pow2(max(int(rest.max(initial=0)), 1), 8)
+            if deg[wide[k - 1]] > width:        # the k widest all leave
+                return _Split(wide[:k], width,
+                              int(deg.sum() - deg[wide[:k]].sum()))
+        return _Split(wide[:0], _pow2(max(self.nbr.shape[1], 1), 8),
+                      int(deg.sum()))
+
     def bucket_key(self) -> Tuple[int, int, int, bool]:
-        n, d = self.nbr.shape
         # max_moves is adaptive per lane, NOT sub-bucketed: the fused
         # kernel's grid runs one lane at a time, so each lane's move
         # loop terminates at its own budget — mixing small budgets with
@@ -216,7 +246,8 @@ class FMWork:
         # while_loop select-masks finished lanes, so per-lane results
         # are budget-composition-independent there too.)  Fewer buckets
         # ⇒ fewer compiles and wider lane stacks per dispatch.
-        return (_pow2(n), _pow2(max(d, 1), 8), self.passes, self.pos_only)
+        return (_pow2(self.nbr.shape[0]), self.split.width, self.passes,
+                self.pos_only)
 
 
 @dataclasses.dataclass
@@ -230,14 +261,36 @@ class _Lanes:
     eps: np.ndarray
     max_moves: np.ndarray
     n_pert: np.ndarray
+    side_id: np.ndarray                 # (k, SIDE_K) — broadcast view
+    side_cnt: np.ndarray                # (k, SIDE_K, n_pad) — broadcast view
 
 
-def _prepare_lanes(w: FMWork) -> _Lanes:
-    n, d = w.nbr.shape
+def _prepare_lanes(w: FMWork, full_width: Optional[int] = None) -> _Lanes:
+    """Pad ``w`` to its bucket, its side rows left out of the table; with
+    ``full_width`` the table keeps them, padded to that width (the
+    oracle rung, which has no side rows)."""
+    n = w.nbr.shape[0]
     n_pad, d_pad = w.bucket_key()[:2]
     k_inst = _pow2(w.k_inst, 2)
+    side = w.split.rows if full_width is None else ()
+    side_id = np.full(SIDE_K, n_pad, np.int32)
+    side_id[:len(side)] = side
+    side_cnt = np.zeros((SIDE_K, n_pad), np.float32)
+    nbr = w.nbr
+    if len(side):
+        nbr = nbr.copy()
+        for h, r in enumerate(side):
+            row = nbr[r][nbr[r] >= 0]
+            side_cnt[h] = np.bincount(row, minlength=n_pad)
+        nbr[side] = -1
+        if (nbr[:, d_pad:] >= 0).any():          # left-pack the rows
+            nbr = np.take_along_axis(
+                nbr, np.argsort(nbr < 0, axis=1, kind="stable"), axis=1)
+        nbr = nbr[:, :d_pad]
+    else:
+        d_pad = full_width or d_pad
     nbr_p = -np.ones((n_pad, d_pad), np.int32)
-    nbr_p[:n, :d] = w.nbr
+    nbr_p[:n, :nbr.shape[1]] = nbr
     vw_p = np.zeros(n_pad, np.int32)
     vw_p[:n] = w.vwgt
     lock_p = np.ones(n_pad, bool)
@@ -259,7 +312,9 @@ def _prepare_lanes(w: FMWork) -> _Lanes:
         parts0=parts0, keys=keys,
         eps=np.full(k_inst, w.eps_frac, np.float32),
         max_moves=np.full(k_inst, max_moves, np.int32),
-        n_pert=np.full(k_inst, w.n_pert, np.int32))
+        n_pert=np.full(k_inst, w.n_pert, np.int32),
+        side_id=np.broadcast_to(side_id, (k_inst, SIDE_K)),
+        side_cnt=np.broadcast_to(side_cnt, (k_inst, SIDE_K, n_pad)))
 
 
 def _select_best(w: FMWork, parts: np.ndarray, sep_w: np.ndarray,
@@ -321,7 +376,10 @@ def execute_fm_works(works: Sequence[FMWork],
     for i, w in enumerate(works):
         groups[w.bucket_key()].append(i)
     for (n_pad, d_pad, passes, pos_only), idxs in groups.items():
-        lanes = [_prepare_lanes(works[i]) for i in idxs]
+        # the oracle rung is the reference on the whole table
+        full = (max(_pow2(works[i].nbr.shape[1], 8) for i in idxs)
+                if mode == "oracle" else None)
+        lanes = [_prepare_lanes(works[i], full) for i in idxs]
         counts = [ln.parts0.shape[0] for ln in lanes]
         L_real = sum(counts)
         # Lane padding to a multiple of 8: dead lanes still pay the vmapped
@@ -347,6 +405,8 @@ def execute_fm_works(works: Sequence[FMWork],
         eps_b = cat(lambda ln: ln.eps, True)
         mm_b = cat(lambda ln: ln.max_moves, False)  # dummies: 0 moves
         np_b = cat(lambda ln: ln.n_pert, True)
+        sid_b = cat(lambda ln: ln.side_id, True)
+        scnt_b = cat(lambda ln: ln.side_cnt, True)
         from repro import obs
         from repro.core.dgraph import _note_launch
 
@@ -354,8 +414,9 @@ def execute_fm_works(works: Sequence[FMWork],
             return jax.device_get(fm_refine_batch(
                 jnp.asarray(nbr_b), jnp.asarray(vw_b), jnp.asarray(parts_b),
                 jnp.asarray(lock_b), jnp.asarray(keys_b), jnp.asarray(eps_b),
-                jnp.asarray(mm_b), jnp.asarray(np_b), passes=passes,
-                pos_only=pos_only, mode=mode, gain_mode=gain_mode))
+                jnp.asarray(mm_b), jnp.asarray(np_b), jnp.asarray(sid_b),
+                jnp.asarray(scnt_b), passes=passes, pos_only=pos_only,
+                mode=mode, gain_mode=gain_mode))
 
         # the compiled program does not depend on the lanes' move
         # budgets (max_moves is traced lane data in both modes), so the
@@ -364,16 +425,20 @@ def execute_fm_works(works: Sequence[FMWork],
         # covers all ``passes`` on-device passes of the bucket.
         parts, sep_w, imb, moves = obs.timed_dispatch(
             "fm", "fm",
-            ("fm", mode, n_pad, d_pad, passes, pos_only, gain_mode, L_pad),
+            ("fm", mode, n_pad, full or d_pad, passes, pos_only, gain_mode,
+             L_pad),
             dispatch, lanes=L_real, lanes_pad=L_pad, mode=mode,
             max_moves=int(mm_b.max()),
             bucket=(n_pad, d_pad, passes, pos_only))
-        # real neighbour slots of the real lanes, from the unpadded tables
-        slots = sum(int(np.count_nonzero(works[i].nbr >= 0)) * k
-                    for i, k in zip(idxs, counts))
+        # real neighbour slots of the real lanes, from the unpadded
+        # tables less their side rows, and the lanes that have side rows
+        slots = sum(works[i].split.slots * k for i, k in zip(idxs, counts))
+        side_lanes = sum(k for i, k in zip(idxs, counts)
+                         if len(works[i].split.rows))
         _note_launch("fm", 0, L_real, L_pad,
                      (n_pad, d_pad, passes, pos_only), passes, 0,
-                     slots=slots, **_launch_counts(moves, L_real))
+                     slots=slots, side_lanes=side_lanes,
+                     **_launch_counts(moves, L_real))
         off = 0
         for i, k in zip(idxs, counts):
             n = works[i].nbr.shape[0]

@@ -62,24 +62,31 @@ BIG_NOISE = 1e9
 #: Width of the pulled list one scatter round of the move loop carries.
 #: Rows of width ``d <= PULL_K`` take the whole row as the list.
 PULL_K = 8
+#: The most locked rows a lane carries outside its table (side rows).
+SIDE_K = 2
 
 
-def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
-                 pulled1, w0, w1, ws, bpart, bws, bimb, noise, pert,
-                 max_moves, pos_only: bool = False):
+def fm_move_loop(nbrs, valid, vwgt_f, locked, side_id, side_cnt, eps_abs,
+                 part, pulled0, pulled1, w0, w1, ws, bpart, bws, bimb,
+                 noise, pert, max_moves, pos_only: bool = False):
     """One FM pass (a bounded sequence of moves) on a single lane.
 
     The per-lane data-plane primitive shared by the hoisted path (under
     ``jax.vmap`` in ``core.fm.fm_refine_multi``) and the fused kernel
     (called per grid lane inside ``_fm_fused_kernel``) — one definition,
-    so the two paths cannot drift.  Returns the pass's state, then its
-    move counters ``iters`` (moves run), ``last_better`` (one past the
-    last move that improved the best state, 0 if none did) and
-    ``pull_overflow`` (moves whose pulled set took more than one scatter
-    round, i.e. pulled more than ``PULL_K`` slots).
+    so the two paths cannot drift.  Returns the pass's state, its gains
+    ``pulled`` (2, n) after the last move, then its move counters
+    ``iters`` (moves run), ``last_better`` (one past the last move that
+    improved the best state, 0 if none did) and ``pull_overflow`` (moves
+    whose pulled set took more than one scatter round, i.e. pulled more
+    than ``PULL_K`` slots).  ``side_id``
+    (``SIDE_K``,) and ``side_cnt`` (``SIDE_K``, n) are the lane's side
+    rows (module docstring); the slots of v's row that point at a side
+    row stay in the table and count as pulled slots.
     """
     n, d = nbrs.shape
     compact = d > PULL_K
+    side_w = jnp.take(vwgt_f, side_id, mode="clip")     # id n: no slot hits
 
     def move_cond(carry):
         i, alive, *_ = carry
@@ -94,7 +101,8 @@ def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
         are compacted (cumsum rank, dense ``(PULL_K, d)`` compare) to
         ``PULL_K`` rows per round, in ``ceil(pulled slots / PULL_K)``
         rounds; a duplicated slot stays a row of its own, so every target
-        gets the same addends as a full ``d x d`` update."""
+        gets the same addends as a full ``d x d`` update.  A pulled side
+        row's addends come from ``side_cnt`` in one dense update."""
         (i, alive, part, moved, pulled, w0, w1, ws, bpart, bws, bimb,
          last_better, overflow) = carry
         pulled0, pulled1 = pulled[0], pulled[1]
@@ -165,7 +173,14 @@ def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
                 lambda c: c[0] * PULL_K < n_pull,
                 lambda c: (c[0] + 1, scatter(c[1], *chunk(c[0]))),
                 (jnp.int32(1), flat))
-        pulled = flat.reshape(2, n)
+        # pulled side rows, once per slot of v's row that points at one:
+        # one dense update of the half ``side`` (``to_u``)
+        hit = pull_slot[None, :] & (nv[None, :] == side_id[:, None])
+        hits = jnp.sum(hit, axis=1).astype(jnp.float32)    # (SIDE_K,)
+        d_side = sum(hits[h] * side_w[h] * side_cnt[h]     # no dot: exact
+                     for h in range(side_id.shape[0]))
+        pulled = flat.reshape(2, n) - jnp.where(
+            jnp.arange(2)[:, None] == side, d_side[None, :], 0.0)
         # weights
         dv = jnp.where(ok, dv_w, 0.0)
         w0 = w0 + jnp.where(side == 0, dv, 0.0) - jnp.where(side == 1, pulled_w, 0.0)
@@ -188,9 +203,10 @@ def fm_move_loop(nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0,
              jnp.stack([pulled0, pulled1]), w0, w1, ws, bpart, bws, bimb,
              jnp.int32(0), jnp.int32(0))
     carry = jax.lax.while_loop(move_cond, move_body, carry)
-    (iters, _, part, _, _, w0, w1, ws, bpart, bws, bimb,
+    (iters, _, part, _, pulled, w0, w1, ws, bpart, bws, bimb,
      last_better, overflow) = carry
-    return part, w0, w1, ws, bpart, bws, bimb, iters, last_better, overflow
+    return (part, w0, w1, ws, bpart, bws, bimb, pulled, iters, last_better,
+            overflow)
 
 
 def fm_noise(keys, n: int, passes: int) -> jax.Array:
@@ -209,15 +225,17 @@ def fm_noise(keys, n: int, passes: int) -> jax.Array:
     return jnp.stack(noises, axis=1)
 
 
-def _fm_fused_kernel(nbr_ref, vwgt_ref, part_ref, locked_ref, noise_ref,
-                     eps_ref, mm_ref, np_ref, part_out, bws_out, bimb_out,
-                     moves_out, *, passes, pos_only):
+def _fm_fused_kernel(nbr_ref, vwgt_ref, part_ref, locked_ref, sid_ref,
+                     scnt_ref, noise_ref, eps_ref, mm_ref, np_ref, part_out,
+                     bws_out, bimb_out, moves_out, *, passes, pos_only):
     nbr = nbr_ref[0]                          # (n, d) int32, lane-resident
     n, d = nbr.shape
     valid = nbr >= 0
     nbrs = jnp.where(valid, nbr, 0)
     vwgt_f = vwgt_ref[0]                      # (n,) f32
     locked = locked_ref[0] != 0
+    side_id = sid_ref[0]                      # (SIDE_K,) int32
+    side_cnt = scnt_ref[0]                    # (SIDE_K, n) f32
     noise_all = noise_ref[0]                  # (passes, 2, n)
     eps_abs = eps_ref[0, 0]                   # per-lane scalars ride as
     max_moves = mm_ref[0, 0]                  # (1, 1) blocks (adaptive
@@ -245,10 +263,10 @@ def _fm_fused_kernel(nbr_ref, vwgt_ref, part_ref, locked_ref, noise_ref,
         wn = jnp.where(valid, wn, 0.0)
         pulled0 = jnp.sum(wn * (pn == 1), axis=1)
         pulled1 = jnp.sum(wn * (pn == 0), axis=1)
-        (part, w0, w1, ws, bpart, bws, bimb, *counts) = fm_move_loop(
-            nbrs, valid, vwgt_f, locked, eps_abs, part, pulled0, pulled1,
-            w0, w1, ws, bpart, bws, bimb, noise, pert, max_moves,
-            pos_only=pos_only)
+        (part, w0, w1, ws, bpart, bws, bimb, _, *counts) = fm_move_loop(
+            nbrs, valid, vwgt_f, locked, side_id, side_cnt, eps_abs, part,
+            pulled0, pulled1, w0, w1, ws, bpart, bws, bimb, noise, pert,
+            max_moves, pos_only=pos_only)
         moves = moves.at[p].set(jnp.stack(counts))
         part = bpart                           # revert to best
         w0, w1, ws = sums(part)
@@ -267,15 +285,16 @@ def _fm_fused_kernel(nbr_ref, vwgt_ref, part_ref, locked_ref, noise_ref,
 @functools.partial(jax.jit, static_argnames=("passes", "pos_only",
                                              "interpret"))
 def fm_fused_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
-                   max_moves, n_pert, passes: int = 3,
+                   max_moves, n_pert, side_id, side_cnt, passes: int = 3,
                    pos_only: bool = False, interpret: bool = True):
     """Fused FM over a flat lane axis — the on-device pass loop.
 
     Same contract and shapes as ``core.fm.fm_refine_multi`` (L = lanes):
     nbr (L, n, d) int32; vwgt (L, n); parts_init (L, n) int8; locked
     (L, n) bool; keys (L, 2) uint32; eps_frac (L,) f32; max_moves,
-    n_pert (L,) int32.  Returns (parts int8, sep_w, imb, moves),
-    bit-identical to the hoisted path.  The compiled program does not
+    n_pert (L,) int32; side_id (L, SIDE_K) int32; side_cnt (L, SIDE_K,
+    n) f32.  Returns (parts int8, sep_w, imb, moves), bit-identical to
+    the hoisted path.  The compiled program does not
     depend on ``max_moves`` (traced lane data), so works with different
     budgets share one executable.
     """
@@ -292,6 +311,8 @@ def fm_fused_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
             pl.BlockSpec((1, n), lambda l: (l, 0)),
             pl.BlockSpec((1, n), lambda l: (l, 0)),
             pl.BlockSpec((1, n), lambda l: (l, 0)),
+            pl.BlockSpec((1, SIDE_K), lambda l: (l, 0)),
+            pl.BlockSpec((1, SIDE_K, n), lambda l: (l, 0, 0)),
             pl.BlockSpec((1, passes, 2, n), lambda l: (l, 0, 0, 0)),
             pl.BlockSpec((1, 1), lambda l: (l, 0)),
             pl.BlockSpec((1, 1), lambda l: (l, 0)),
@@ -311,6 +332,7 @@ def fm_fused_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
         ],
         interpret=interpret,
     )(nbr, vwgt_f, parts_init.astype(jnp.int32),
-      locked.astype(jnp.int32), noise,
+      locked.astype(jnp.int32), side_id.astype(jnp.int32),
+      side_cnt.astype(jnp.float32), noise,
       eps_abs[:, None], max_moves[:, None], n_pert[:, None])
     return parts.astype(jnp.int8), bws[:, 0], bimb[:, 0], moves

@@ -47,7 +47,7 @@ def fm_mode_default() -> str:
 
 
 def fm_refine_batch(nbr, vwgt, parts_init, locked, keys, eps_frac,
-                    max_moves, n_pert, passes: int = 3,
+                    max_moves, n_pert, side_id, side_cnt, passes: int = 3,
                     pos_only: bool = False, mode: str | None = None,
                     gain_mode: str | None = None,
                     interpret: bool | None = None):
@@ -58,8 +58,9 @@ def fm_refine_batch(nbr, vwgt, parts_init, locked, keys, eps_frac,
     fused kernel vs the hoisted path (default ``fm_mode_default()``);
     ``oracle`` is the independent jnp reference (``kernels.ref``) — the
     recovery ladder's last kernel rung (DESIGN.md §8), sharing no code
-    with the other two.  ``gain_mode`` only applies to the hoisted
-    path's per-pass gain recompute backend.  All modes return
+    with the other two; it ignores ``side_id`` / ``side_cnt`` and takes
+    the whole table, side rows included.  ``gain_mode`` only applies to
+    the hoisted path's per-pass gain recompute backend.  All modes return
     bit-identical results, ``(parts, sep_w, imb, moves)``, the move
     counters included (asserted in ``tests/test_fm_fused.py`` and
     ``tests/test_fm_counters.py``); the oracle's ``moves`` lacks the
@@ -71,8 +72,9 @@ def fm_refine_batch(nbr, vwgt, parts_init, locked, keys, eps_frac,
         if interpret is None:
             interpret = _interpret_default()
         return fm_fused_multi(nbr, vwgt, parts_init, locked, keys,
-                              eps_frac, max_moves, n_pert, passes=passes,
-                              pos_only=pos_only, interpret=interpret)
+                              eps_frac, max_moves, n_pert, side_id,
+                              side_cnt, passes=passes, pos_only=pos_only,
+                              interpret=interpret)
     if mode == "oracle":
         from repro.kernels.fm_fused import fm_noise
         from repro.kernels.ref import fm_fused_ref
@@ -92,8 +94,9 @@ def fm_refine_batch(nbr, vwgt, parts_init, locked, keys, eps_frac,
     if gain_mode is None:
         gain_mode = gain_mode_default()
     return fm_refine_multi(nbr, vwgt, parts_init, locked, keys, eps_frac,
-                           max_moves, n_pert, passes=passes,
-                           pos_only=pos_only, gain_mode=gain_mode)
+                           max_moves, n_pert, side_id, side_cnt,
+                           passes=passes, pos_only=pos_only,
+                           gain_mode=gain_mode)
 
 
 def ell_relax_step(nbr: jax.Array, dist_ext: jax.Array, big) -> jax.Array:

@@ -4,11 +4,12 @@ carried on the ``launch`` event, and free of new executables.
 * hoisted, fused (interpret) and oracle FM return the same counters on
   the parity suite's lane stacks, beside the same partitions;
 * a lane with no move budget counts no iteration;
-* a band graph's anchor widens its table, and ``slots`` counts only the
-  real neighbours;
+* a band graph's anchors leave its table, ``slots`` counts the real
+  neighbours of the rows that stay, and ``side_lanes`` the lanes whose
+  anchors left;
 * ``execute_fm_works`` puts ``trips``, ``lane_iters``,
-  ``iters_after_best``, ``slots`` and, but for the oracle,
-  ``pull_overflow`` on its ``launch``;
+  ``iters_after_best``, ``slots``, ``side_lanes`` and, but for the
+  oracle, ``pull_overflow`` on its ``launch``;
 * tracing on or off dispatches the same executables;
 * the host steps of nested dissection emit their ``stage`` events.
 """
@@ -28,7 +29,8 @@ from repro.kernels.fm_fused import fm_fused_multi  # noqa: E402
 from test_fm_fused import (_assert_bit_identical, _rand_lanes,  # noqa: E402
                            _run_all_three)
 
-LAUNCH_KEYS = ("trips", "lane_iters", "iters_after_best", "slots")
+LAUNCH_KEYS = ("trips", "lane_iters", "iters_after_best", "slots",
+               "side_lanes")
 DEVICE_KEYS = LAUNCH_KEYS + ("pull_overflow",)     # not the oracle's
 
 
@@ -103,19 +105,20 @@ def _plane_band(k=7, width=1):
     return extract_band(g, part, width=width)
 
 
-def test_anchor_widens_the_table():
+def test_anchor_leaves_the_table():
     band, bpart, locked, _ = _plane_band()
     deg = band.degrees()
-    anchor = int(deg[-2:].max())
-    assert anchor == 49 > int(deg[:-2].max())       # one 7x7 plane
+    assert int(deg[-2:].min()) == 49 > 32 > int(deg[:-2].max()) == 26
     nbr, _ = band.to_ell()
+    assert nbr.shape[1] == 49                       # one 7x7 plane
     w = FMWork(nbr=nbr, vwgt=band.vwgt, part=bpart, locked=locked,
                seed=3, k_inst=4)
     _, ev = _collect(lambda: execute_fm_works([w], mode="hoisted"))
     (launch,) = ev.of("launch")
-    assert launch["slots"] == 4 * int(deg.sum())
+    assert launch["slots"] == 4 * int(deg[:-2].sum())
+    assert launch["side_lanes"] == 4
     n_pad, d_pad = launch["bucket"][:2]
-    assert d_pad == 64 and launch["slots"] < 4 * n_pad * d_pad
+    assert d_pad == 32 and launch["slots"] < 4 * n_pad * d_pad
 
 
 @pytest.mark.parametrize("mode", ["hoisted", "fused", "oracle"])
@@ -136,7 +139,9 @@ def test_launch_carries_the_move_counters(mode):
     assert launch["trips"] <= launch["lane_iters"] \
         <= lanes * launch["trips"]
     assert 0 <= launch["iters_after_best"] <= launch["lane_iters"]
-    assert launch["slots"] == lanes * int(band.degrees().sum())
+    # the bucket's counts, whichever rung ran: the oracle's whole table too
+    assert launch["slots"] == lanes * int(band.degrees()[:-2].sum())
+    assert launch["side_lanes"] == lanes
     if mode == "oracle":
         assert "pull_overflow" not in launch
     else:

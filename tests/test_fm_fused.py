@@ -32,8 +32,8 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.core.fm import (FMWork, execute_fm_works,  # noqa: E402
                            fm_refine_multi, refine_parts)
-from repro.kernels.fm_fused import (PULL_K, fm_fused_multi,  # noqa: E402
-                                    fm_noise)
+from repro.kernels.fm_fused import (PULL_K, SIDE_K,  # noqa: E402
+                                    fm_fused_multi, fm_noise)
 from repro.kernels.ops import fm_mode_default  # noqa: E402
 from repro.kernels.ref import fm_fused_ref  # noqa: E402
 
@@ -59,11 +59,18 @@ def _rand_lanes(seed: int, L: int, n: int, d: int,
                  (nbr, vwgt, part, locked, keys, eps, mm, n_pert))
 
 
+def _no_side(L: int, n: int):
+    """``side_id`` / ``side_cnt`` of lanes without side rows."""
+    return (jnp.full((L, SIDE_K), n, jnp.int32),
+            jnp.zeros((L, SIDE_K, n), jnp.float32))
+
+
 def _run_all_three(args, passes: int, pos_only: bool):
     nbr, vwgt, parts0, locked, keys, eps, mm, n_pert = args
-    hoisted = fm_refine_multi(*args, passes=passes, pos_only=pos_only,
-                              gain_mode="jnp")
-    fused = fm_fused_multi(*args, passes=passes, pos_only=pos_only,
+    side = _no_side(*vwgt.shape)
+    hoisted = fm_refine_multi(*args, *side, passes=passes,
+                              pos_only=pos_only, gain_mode="jnp")
+    fused = fm_fused_multi(*args, *side, passes=passes, pos_only=pos_only,
                            interpret=True)
     noise = fm_noise(keys, nbr.shape[1], passes)
     eps_abs = eps * vwgt.astype(jnp.float32).sum(axis=1)

@@ -24,9 +24,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 L = 8
 #: (n, d) ELL widths: 2-D/3-D 7-point meshes, and 27-point meshes
 WIDTHS = [(4096, 8), (32768, 32)]
-#: (n, d) FM widths of anchored band graphs: an anchor joined to a whole
-#: band layer widens the rows past ``PULL_K`` (the 27-point mesh of 13^3)
-FM_WIDTHS = [(2048, 256), (1024, 128)]
+#: (n, d) FM widths of band graphs, their anchors carried as side rows
+#: (the 27-point mesh of 13^3), and of tables that unlocked hub rows
+#: still widen past ``PULL_K``
+FM_WIDTHS = [(2048, 32), (2048, 64), (1024, 32), (2048, 256), (1024, 128)]
 
 
 @pytest.fixture(scope="module")
@@ -78,13 +79,15 @@ def test_bfs_compiles(one_chip, n, d):
 
 @pytest.mark.parametrize("n,d", WIDTHS + FM_WIDTHS)
 def test_fm_hoisted_jnp_compiles(one_chip, n, d):
-    from repro.core.fm import fm_refine_multi
+    from repro.core.fm import SIDE_K, fm_refine_multi
     c = one_chip
     fm_refine_multi.lower(
         _sds((L, n, d), jnp.int32, c), _sds((L, n), jnp.int32, c),
         _sds((L, n), jnp.int8, c), _sds((L, n), jnp.bool_, c),
         _sds((L, 2), jnp.uint32, c), _sds((L,), jnp.float32, c),
         _sds((L,), jnp.int32, c), _sds((L,), jnp.int32, c),
+        _sds((L, SIDE_K), jnp.int32, c),
+        _sds((L, SIDE_K, n), jnp.float32, c),
         passes=3, pos_only=False, gain_mode="jnp").compile()
 
 
